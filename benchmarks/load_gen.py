@@ -17,6 +17,10 @@ completed/cancelled/rejected/error counts per tenant.
 ``--verify-direct`` replays every finished prompt through an in-process
 engine built from the same ``ModelSpec`` and hard-asserts the SSE token
 streams are bit-identical (completed) or an exact prefix (cancelled).
+That mirror engine runs in THIS process, pinned to the CPU before JAX is
+imported: a chip belongs to one process, and the server holds it.  So
+``--verify-direct`` can only mirror a server that runs on the CPU; a
+server on the chip cannot be mirrored by a second process.
 The direct engine deliberately uses a DIFFERENT scheduler config than
 the server: greedy committed streams are schedule/drafter/depth
 independent (the engine's losslessness contract), so any mismatch is a
@@ -263,8 +267,9 @@ def verify_direct(args, recs: list) -> dict:
               "both for --verify-direct", file=sys.stderr)
     spec = ModelSpec.from_args(args)
     print(f"[load] verify-direct: building {spec} ...", flush=True)
-    _cfg, model, params, _tasks, state = build_model_bundle(spec)
-    eng = ServingEngine(model, params, state, scheduler="continuous",
+    bundle = build_model_bundle(spec)
+    eng = ServingEngine(bundle.model, bundle.params, bundle.state,
+                        scheduler="continuous",
                         num_slots=4, max_new=args.output_max, learn=True,
                         sync_every=2)
     todo = [r for r in recs if r["outcome"] in ("completed", "cancelled")]
@@ -334,6 +339,9 @@ def main(argv=None) -> int:
     from repro.serving.config import ModelSpec
     ModelSpec.add_args(ap)
     args = ap.parse_args(argv)
+    if args.verify_direct:
+        # the mirror engine must not reach for the chip the server holds
+        os.environ["JAX_PLATFORMS"] = "cpu"
     if args.smoke:
         args.requests = min(args.requests, 10)
         args.rate = max(args.rate, 20.0)

@@ -1,4 +1,4 @@
-"""Qwen3-1.7B [hf:Qwen/Qwen3-8B family] — dense, GQA, per-head qk RMSNorm."""
+"""Qwen3-1.7B [hf:Qwen/Qwen3-1.7B] — dense, GQA, per-head qk RMSNorm."""
 from repro.configs.base import DVIConfig, ModelConfig
 
 CONFIG = ModelConfig(
@@ -15,7 +15,7 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     rope_theta=1_000_000.0,
     dvi=DVIConfig(split_layer=2),
-    citation="hf:Qwen/Qwen3-8B",
+    citation="hf:Qwen/Qwen3-1.7B",
 )
 
 TINY = CONFIG.replace(

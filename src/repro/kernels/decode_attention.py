@@ -5,13 +5,21 @@ point choosing ref vs Pallas vs paged); this module only holds the
 contiguous Pallas implementation.
 
 TPU adaptation of flash-decoding: the KV sequence is blocked; each grid
-step stages one (bs, hd) K/V tile HBM->VMEM, updates an online-softmax
-accumulator (m, l, acc) held in VMEM scratch for the whole q-head *group*
-sharing that KV head (GQA: G = H / KV query heads per KV head), and the
-normalized output is written once on the last block.  Length masking uses
-the per-sequence cache length (slots >= length are dead speculative writes).
+step stages one (bs, KV*hd) K/V tile HBM->VMEM holding EVERY KV head, and
+updates one online-softmax accumulator (m, l, acc) per KV head, held in
+VMEM scratch for the whole q-head *group* sharing that head (GQA: G = H / KV
+query heads per KV head).  The normalized output is written once on the
+last block.  Length masking uses the per-sequence cache length (slots >=
+length are dead speculative writes); the lengths ride in as a
+scalar-prefetch operand (SMEM).
 
-Grid: (B, KV, S/bs) — batch and kv-head parallel, seq innermost sequential.
+K/V are viewed as (B, S, KV*hd) — a free reshape of the cache layout — so
+a tile's last two dimensions are (bs, KV*hd) and each head is a lane-aligned
+``hd``-wide column slice (the TPU tiling wants the second-to-last block dim
+a multiple of 8 or the whole axis, which a single-head ``(bs, 1, hd)`` block
+is not).
+
+Grid: (B, S/bs) — batch parallel, seq innermost sequential.
 """
 from __future__ import annotations
 
@@ -23,15 +31,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 NEG = -1e30
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, bs: int, scale: float):
-    s = pl.program_id(2)
-    nsb = pl.num_programs(2)
+            *, bs: int, hd: int, kv: int, scale: float):
+    b = pl.program_id(0)
+    s = pl.program_id(1)
+    nsb = pl.num_programs(1)
 
     @pl.when(s == 0)
     def _init():
@@ -39,29 +46,31 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                                # (G, hd)
-    k = k_ref[0, :, 0, :]                          # (bs, hd)
-    v = v_ref[0, :, 0, :]
-    length = len_ref[0]
+    length = len_ref[b]
+    for h in range(kv):
+        q = q_ref[0, h]                            # (G, hd)
+        k = k_ref[0, :, h * hd:(h + 1) * hd]       # (bs, hd)
+        v = v_ref[0, :, h * hd:(h + 1) * hd]
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale          # (G, bs)
+        slot = s * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        scores = jnp.where(slot < length, scores, NEG)
 
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (G, bs)
-    slot = s * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(slot < length, scores, NEG)
-
-    m_prev = m_ref[...]                            # (G,)
-    m_cur = jnp.maximum(m_prev, scores.max(axis=-1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(scores - m_cur[:, None])           # (G, bs)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                    + jnp.dot(p, v.astype(jnp.float32),
-                              preferred_element_type=jnp.float32))
-    m_ref[...] = m_cur
+        m_prev = m_ref[h]                          # (G, 1)
+        m_cur = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(scores - m_cur)                # (G, bs)
+        l_ref[h] = l_ref[h] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = (acc_ref[h] * alpha
+                      + jnp.dot(p, v.astype(jnp.float32),
+                                preferred_element_type=jnp.float32))
+        m_ref[h] = m_cur
 
     @pl.when(s == nsb - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -77,27 +86,33 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         pad = ((0, 0), (0, Sp - S), (0, 0), (0, 0))
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
+    k = k.reshape(B, Sp, KV * hd)
+    v = v.reshape(B, Sp, KV * hd)
     qg = q.reshape(B, KV, G, hd)
     scale = 1.0 / math.sqrt(hd)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, scale=scale),
-        grid=(B, KV, Sp // bs),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, Sp // bs),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, s: (b,)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd), lambda b, h, s: (b, s, h, 0)),
+            pl.BlockSpec((1, KV, G, hd), lambda b, s, lens: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, KV * hd), lambda b, s, lens: (b, s, 0)),
+            pl.BlockSpec((1, bs, KV * hd), lambda b, s, lens: (b, s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, KV, G, hd),
+                               lambda b, s, lens: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, bs=bs, hd=hd, kv=KV, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths, qg, k, v)
+    )(lengths.astype(jnp.int32), qg, k, v)
     return out.reshape(B, H, hd)

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _kernel(h_ref, w_ref, a_ref, b_ref, out_ref, u_ref, *, gamma: float):
     j = pl.program_id(1)
@@ -62,7 +60,7 @@ def lora_logits(h: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((bt, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Tp, Vp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, r), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(h, w, a, b)

@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels — the single dispatch point.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the kernel
-body executes in Python for bit-faithful validation against the ref.py
-oracles; on a real TPU backend the same calls compile to Mosaic.  Set
-``REPRO_FORCE_INTERPRET=0`` to force compiled mode.
+On a TPU backend the kernels compile to Mosaic.  On the CPU backend they
+run in ``interpret=True`` mode — the kernel body executes in Python for
+validation against the ref.py oracles.  Any other backend is an error: a
+kernel never falls back silently.
 
 ``decode_attention`` dispatches across the three implementations by
 argument/`impl`: the pure-jnp oracle (``impl="ref"``), the contiguous
@@ -13,7 +13,6 @@ entry point, since the paged cache has different operands).
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -30,10 +29,12 @@ from repro.kernels.verify_argmax import verify_argmax as _verify_argmax
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_FORCE_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                           f"{backend!r}: they compile for 'tpu' and "
+                           f"interpret on 'cpu' only")
+    return backend == "cpu"
 
 
 @partial(jax.jit, static_argnames=("block_t", "block_v"))

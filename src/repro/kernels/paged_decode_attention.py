@@ -16,8 +16,13 @@ garbage never reaches the accumulator.  A page holding slots past the
 lane's length (the eager speculative tail) is masked per-slot by
 ``j < length``.
 
-Grid: (B, KV, max_pages) — batch and kv-head parallel, logical pages
-innermost sequential.  **Per-lane early-out**: pages at or beyond the
+Each grid step stages one whole page ``(ps, KV*hd)`` — every KV head,
+viewed through a free reshape so the tile's last two dimensions are
+(page, KV*hd) as the TPU tiling requires — and runs one online-softmax
+update per KV head on its lane-aligned ``hd``-wide column slice.
+
+Grid: (B, max_pages) — batch parallel, logical pages innermost
+sequential.  **Per-lane early-out**: pages at or beyond the
 lane's active page count contribute nothing, so the index map clamps them
 onto the lane's LAST active page (a repeated block index means Mosaic
 skips the DMA — the tile is already resident) and the kernel body skips
@@ -38,15 +43,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 NEG = -1e30
 
 
 def _kernel(tbl_ref, len_ref, pc_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-            l_ref, acc_ref, *, ps: int, scale: float):
+            l_ref, acc_ref, *, ps: int, hd: int, kv: int, scale: float):
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
     pc = pc_ref[b]
 
     @pl.when(p == 0)
@@ -57,30 +60,32 @@ def _kernel(tbl_ref, len_ref, pc_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
 
     @pl.when(p < pc)
     def _update():
-        q = q_ref[0, 0]                            # (G, hd)
-        k = k_ref[0, :, 0, :]                      # (ps, hd)
-        v = v_ref[0, :, 0, :]
         length = len_ref[b]
         mapped = tbl_ref[b, p] >= 0
+        for h in range(kv):
+            q = q_ref[0, h]                        # (G, hd)
+            k = k_ref[0, :, h * hd:(h + 1) * hd]   # (ps, hd)
+            v = v_ref[0, :, h * hd:(h + 1) * hd]
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale      # (G, ps)
+            j = p * ps + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            scores = jnp.where(mapped & (j < length), scores, NEG)
 
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        j = p * ps + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        scores = jnp.where(mapped & (j < length), scores, NEG)
-
-        m_prev = m_ref[...]                        # (G,)
-        m_cur = jnp.maximum(m_prev, scores.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        pexp = jnp.exp(scores - m_cur[:, None])    # (G, ps)
-        l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jnp.dot(pexp, v.astype(jnp.float32),
-                                  preferred_element_type=jnp.float32))
-        m_ref[...] = m_cur
+            m_prev = m_ref[h]                      # (G, 1)
+            m_cur = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            pexp = jnp.exp(scores - m_cur)         # (G, ps)
+            l_ref[h] = l_ref[h] * alpha + pexp.sum(axis=-1, keepdims=True)
+            acc_ref[h] = (acc_ref[h] * alpha
+                          + jnp.dot(pexp, v.astype(jnp.float32),
+                                    preferred_element_type=jnp.float32))
+            m_ref[h] = m_cur
 
     @pl.when(p == pc - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
@@ -101,35 +106,41 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         page_counts = (lengths.astype(jnp.int32) + ps - 1) // ps
     page_counts = jnp.clip(page_counts.astype(jnp.int32), 1, MPS)
 
-    def kv_map(b, h, p, tbl, lens, pc):
+    # pages viewed as (P, ps, KV*hd): a free reshape whose tiles hold every
+    # KV head, each a lane-aligned hd-wide column slice
+    k_pages = k_pages.reshape(P, ps, KV * hd)
+    v_pages = v_pages.reshape(P, ps, KV * hd)
+
+    def kv_map(b, p, tbl, lens, pc):
         # beyond the lane's active pages: revisit the last active page so
         # the pipeline issues no new DMA for the skipped grid steps
         pe = jnp.minimum(p, pc[b] - 1)
-        return (jnp.maximum(tbl[b, pe], 0), 0, h, 0)
+        return (jnp.maximum(tbl[b, pe], 0), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, KV, MPS),
+        grid=(B, MPS),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd),
-                         lambda b, h, p, tbl, lens, pc: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
+            pl.BlockSpec((1, KV, G, hd),
+                         lambda b, p, tbl, lens, pc: (b, 0, 0, 0)),
+            pl.BlockSpec((1, ps, KV * hd), kv_map),
+            pl.BlockSpec((1, ps, KV * hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, p, tbl, lens, pc: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, hd),
+                               lambda b, p, tbl, lens, pc: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, ps=ps, scale=scale),
+        functools.partial(_kernel, ps=ps, hd=hd, kv=KV, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, lengths, page_counts, qg, k_pages, v_pages)
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), page_counts,
+      qg, k_pages, v_pages)
     return out.reshape(B, H, hd)

@@ -2,11 +2,22 @@
 
 TPU formulation: grid (B, T/Q) with the chunk axis sequential; the running
 SSD state (H, hd, ds) lives in VMEM scratch across chunk steps.  Each chunk
-does the intra-chunk quadratic term (two MXU einsums through a (Q, Q, H)
-decay-masked attention-like tensor), the inter-chunk contribution from the
+does, per head, the intra-chunk quadratic term (a (Q, Q) decay-masked
+attention-like matrix on the MXU), the inter-chunk contribution from the
 carried state, and the state update — i.e. the same decomposition as the
 pure-jnp oracle ``repro.kernels.ref.ref_ssd_scan``, with chunk length Q=128
 matched to MXU tiling.
+
+Every in-kernel operation is a 2-D matmul in NN or NT form, an
+elementwise op or a row reduction, which is what Mosaic lowers:
+
+* x and y travel head-major and time-minor, ``(B, H*hd, T)``, so a head is
+  an aligned ``hd``-row slice of the tile and both y terms are NT matmuls;
+* dt travels as ``(B, H, T)`` (one row per head) and A as a scalar-prefetch
+  operand (SMEM);
+* the in-chunk inclusive cumsum of ``dt * A`` is a triangular matmul
+  (Mosaic has no cumsum lowering), taken as a row; the column the
+  pairwise decay needs is an identity matmul of that row.
 
 G (B/C groups) == 1 here (Mamba-2 default); dt is pre-softplus-ed by the
 wrapper caller.
@@ -20,11 +31,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+_HI = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))          # contract the last dim of both
 
 
-def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, hout_ref, hstate_ref,
-            *, Q: int):
+def _kernel(a_ref, x_ref, b_ref, c_ref, dt_ref, y_ref, hout_ref, hstate_ref,
+            *, Q: int, H: int, hd: int):
     ci = pl.program_id(1)
     nc = pl.num_programs(1)
 
@@ -32,32 +44,50 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, hout_ref, hstate_ref,
     def _init():
         hstate_ref[...] = jnp.zeros_like(hstate_ref)
 
-    x = x_ref[0].astype(jnp.float32)               # (Q, H, hd)
     Bc = b_ref[0].astype(jnp.float32)              # (Q, ds)   (G == 1)
     Cc = c_ref[0].astype(jnp.float32)              # (Q, ds)
-    dt = dt_ref[0].astype(jnp.float32)             # (Q, H)
-    A = a_ref[...]                                 # (H,)
-
-    dA = dt * A[None, :]                           # (Q, H)
-    cum = jnp.cumsum(dA, axis=0)                   # inclusive
-    seg = cum[:, None, :] - cum[None, :, :]        # (Q, Q, H)
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    tri = (jj <= ii)[:, :, None]
-    decay = jnp.where(tri, jnp.exp(seg), 0.0)      # (Q, Q, H)
-    cb = jnp.dot(Cc, Bc.T, preferred_element_type=jnp.float32)  # (Q, Q)
-    att = cb[:, :, None] * decay * dt[None, :, :]  # (Q, Q, H)
-    y_intra = jnp.einsum("ijh,jhd->ihd", att, x)
+    tri = jj <= ii                                 # [i, j]: j <= i
+    upper = (ii <= jj).astype(jnp.float32)
+    eye = (ii == jj).astype(jnp.float32)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1) == Q - 1
+    cb = jax.lax.dot_general(Cc, Bc, _NT, precision=_HI,
+                             preferred_element_type=jnp.float32)   # (Q, Q)
 
-    # inter-chunk from carried state
-    h_in = hstate_ref[...]                         # (H, hd, ds)
-    y_inter = jnp.einsum("is,hds,ih->ihd", Cc, h_in, jnp.exp(cum))
-    y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
+    for h in range(H):
+        x = x_ref[0, h * hd:(h + 1) * hd, :].astype(jnp.float32)  # (hd, Q)
+        dt = dt_ref[0, h:h + 1, :].astype(jnp.float32)            # (1, Q)
+        dA = dt * a_ref[h]                                        # (1, Q)
+        cum_row = jnp.dot(dA, upper, precision=_HI,
+                          preferred_element_type=jnp.float32)     # (1, Q)
+        # the column and the chunk total are read off cum_row exactly
+        # (products with 0/1 and sums of zeros), so every term agrees on
+        # the same rounding of the cumsum
+        cum_col = jax.lax.dot_general(eye, cum_row, _NT, precision=_HI,
+                                      preferred_element_type=jnp.float32)
+        total = jnp.sum(jnp.where(last, cum_row, 0.0), axis=-1,
+                        keepdims=True)                            # (1, 1)
 
-    # state update: h_out = exp(sum dA) h_in + sum_j exp(cum_last-cum_j) dt_j B_j x_j
-    dec_out = jnp.exp(cum[-1:, :] - cum) * dt      # (Q, H)
-    chunk_state = jnp.einsum("jh,js,jhd->hds", dec_out, Bc, x)
-    hstate_ref[...] = h_in * jnp.exp(cum[-1])[:, None, None] + chunk_state
+        # intra-chunk: att[i, j] = C_i.B_j exp(cum_i - cum_j) dt_j, j <= i
+        decay = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)   # (Q, Q)
+        att = cb * decay * dt
+        y_intra = jax.lax.dot_general(x, att, _NT, precision=_HI,
+                                      preferred_element_type=jnp.float32)
+
+        # inter-chunk from the carried state: y_i += exp(cum_i) C_i h_in
+        h_in = hstate_ref[h]                                      # (hd, ds)
+        y_inter = jax.lax.dot_general(
+            h_in, Cc, _NT, precision=_HI,
+            preferred_element_type=jnp.float32) * jnp.exp(cum_row)
+        y_ref[0, h * hd:(h + 1) * hd, :] = (y_intra + y_inter
+                                            ).astype(y_ref.dtype)
+
+        # state update: h_out = exp(sum dA) h_in + sum_j exp(cum_Q-cum_j) dt_j x_j B_j
+        dec_out = jnp.exp(total - cum_row) * dt                   # (1, Q)
+        chunk_state = jnp.dot(x * dec_out, Bc, precision=_HI,
+                              preferred_element_type=jnp.float32)  # (hd, ds)
+        hstate_ref[h] = h_in * jnp.exp(total) + chunk_state
 
     @pl.when(ci == nc - 1)
     def _finish():
@@ -74,30 +104,34 @@ def ssd_scan(xh: jax.Array, Bc: jax.Array, Cc: jax.Array, dt: jax.Array,
     assert T % chunk == 0
     Q = chunk
     nc = T // Q
-    Bc2 = Bc[:, :, 0, :]
-    Cc2 = Cc[:, :, 0, :]
+    xt = jnp.swapaxes(xh.reshape(B, T, H * hd), 1, 2)       # (B, H*hd, T)
+    dtt = jnp.swapaxes(dt, 1, 2)                             # (B, H, T)
 
-    y, h_final = pl.pallas_call(
-        functools.partial(_kernel, Q=Q),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, nc),
         in_specs=[
-            pl.BlockSpec((1, Q, H, hd), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, Q, ds), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, Q, ds), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, Q, H), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((H,), lambda b, c: (0,)),
+            pl.BlockSpec((1, H * hd, Q), lambda b, c, a: (b, 0, c)),
+            pl.BlockSpec((1, Q, ds), lambda b, c, a: (b, c, 0)),
+            pl.BlockSpec((1, Q, ds), lambda b, c, a: (b, c, 0)),
+            pl.BlockSpec((1, H, Q), lambda b, c, a: (b, 0, c)),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, H, hd), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, H, hd, ds), lambda b, c: (b, 0, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, H, hd), xh.dtype),
-            jax.ShapeDtypeStruct((B, H, hd, ds), jnp.float32),
+            pl.BlockSpec((1, H * hd, Q), lambda b, c, a: (b, 0, c)),
+            pl.BlockSpec((1, H, hd, ds), lambda b, c, a: (b, 0, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((H, hd, ds), jnp.float32)],
-        compiler_params=_CompilerParams(
+    )
+    yt, h_final = pl.pallas_call(
+        functools.partial(_kernel, Q=Q, H=H, hd=hd),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H * hd, T), xh.dtype),
+            jax.ShapeDtypeStruct((B, H, hd, ds), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xh, Bc2, Cc2, dt, A)
+    )(A.astype(jnp.float32), xt, Bc[:, :, 0, :], Cc[:, :, 0, :], dtt)
+    y = jnp.swapaxes(yt, 1, 2).reshape(B, T, H, hd)
     return y, h_final

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 NEG = -1e30
 
 
@@ -74,7 +72,7 @@ def verify_argmax(h: jax.Array, w: jax.Array, *, block_t: int = 128,
             jax.ShapeDtypeStruct((Tp,), jnp.int32),
             jax.ShapeDtypeStruct((Tp,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(h, w)

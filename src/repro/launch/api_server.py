@@ -1,6 +1,6 @@
 """OpenAI-compatible API server over the DVI serving engine.
 
-Builds the tiny-backbone serving stack (``serving.config.ModelSpec``
+Builds the serving stack (``serving.config.ModelSpec``
 recipe: init -> synthetic pretrain -> online trainer state), runs the
 engine on a dedicated thread (``serving.http.EngineDriver``) and serves:
 
@@ -27,6 +27,7 @@ import signal
 import sys
 import threading
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.config import (EngineConfig, ModelSpec,
                                   build_engine, build_model_bundle)
 from repro.serving.http import ApiServer, EngineDriver
@@ -47,8 +48,9 @@ def main(argv=None) -> int:
     print(f"[api] building model: arch={spec.arch} tiny={spec.tiny} "
           f"seed={spec.seed} pretrain_steps={spec.pretrain_steps}",
           flush=True)
-    _cfg, model, params, _tasks, state = build_model_bundle(spec)
-    engine = build_engine(econf, model, params, state)
+    enable_compile_cache()
+    bundle = build_model_bundle(spec)
+    engine = build_engine(econf, bundle.model, bundle.params, bundle.state)
     driver = EngineDriver(engine).start()
     srv = ApiServer((args.host, args.port), driver,
                     model_id=f"{spec.arch}{'-tiny' if spec.tiny else ''}",

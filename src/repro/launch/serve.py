@@ -23,6 +23,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.config import (EngineConfig, ModelSpec, build_engine,
                                   build_model_bundle)
 from repro.serving.engine import Request
@@ -49,8 +50,10 @@ def main():
     if args.trace_out:
         econf.telemetry = True
 
-    cfg, model, params, tasks, state = build_model_bundle(spec)
-    eng = build_engine(econf, model, params, state)
+    enable_compile_cache()
+    bundle = build_model_bundle(spec)
+    tasks = bundle.tasks
+    eng = build_engine(econf, bundle.model, bundle.params, bundle.state)
     t0 = time.monotonic()
     done, handles = [], []
     for i in range(args.requests):

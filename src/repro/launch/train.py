@@ -25,6 +25,7 @@ from repro.checkpoint import save_checkpoint, save_lora
 from repro.configs import get_config
 from repro.core import online as online_mod
 from repro.data import SyntheticTasks, TASK_CATEGORIES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.optim import adamw_init
 from repro.training import make_dvi_train_step, pretrain
@@ -50,6 +51,7 @@ def main():
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--dtype", default="float32")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, tiny=args.tiny).replace(dtype=args.dtype)
     model = build_model(cfg)

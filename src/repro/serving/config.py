@@ -13,11 +13,11 @@ its own argparse subset of them.  This module hoists both:
   shipped across process boundaries (e.g. the load generator re-running
   a server's exact engine in-process for stream verification).
 
-* ``ModelSpec`` + ``build_model_bundle`` — the tiny-backbone recipe the
-  launchers share (config -> init -> synthetic pretrain -> online
-  trainer state), so the HTTP server and the verification path build
-  bit-identical models from the same (arch, tiny, seed, pretrain_steps)
-  tuple.
+* ``ModelSpec`` + ``build_model_bundle`` — the backbone recipe the
+  launchers and ``chip_smoke.py`` share (config -> init -> synthetic
+  pretrain -> online trainer state), so the HTTP server and the
+  verification path build bit-identical models from the same (arch,
+  tiny, seed, pretrain_steps) tuple.
 
 Keep knob names here in lockstep with ``ServingEngine``'s fields — the
 round-trip test (tests/test_config.py) asserts every EngineConfig field
@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import argparse
 from dataclasses import dataclass, field, fields
-from typing import Dict, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 
 def parse_tenant_weights(spec: str) -> Optional[Dict[str, float]]:
@@ -234,11 +234,22 @@ class ModelSpec:
                    seed=args.seed, pretrain_steps=args.pretrain_steps)
 
 
-def build_model_bundle(spec: ModelSpec):
-    """(cfg, model, params, tasks, state): the launcher recipe — config ->
-    init -> synthetic pretrain -> fresh online-trainer state.  Deferred
-    imports keep ``serving.config`` importable without pulling jax at
-    module load (argparse-only callers)."""
+class ModelBundle(NamedTuple):
+    cfg: object
+    model: object
+    params: dict
+    tasks: object                 # SyntheticTasks over the config's vocab
+    state: object                 # fresh OnlineTrainerState
+    pretrain_losses: List[float]  # one per synthetic pretrain step
+
+
+def build_model_bundle(spec: ModelSpec) -> ModelBundle:
+    """The launcher recipe: config -> init -> synthetic pretrain -> fresh
+    online-trainer state.  A tiny backbone computes in float32 (exact
+    argmax comparisons in the CPU tests need it); full width keeps the
+    config's own dtype.  Deferred imports keep ``serving.config``
+    importable without pulling jax at module load (argparse-only
+    callers)."""
     import jax
 
     from repro.configs import get_config
@@ -247,13 +258,15 @@ def build_model_bundle(spec: ModelSpec):
     from repro.models.model import build_model
     from repro.training import pretrain
 
-    cfg = get_config(spec.arch, tiny=spec.tiny).replace(dtype="float32")
+    cfg = get_config(spec.arch, tiny=spec.tiny)
+    if spec.tiny:
+        cfg = cfg.replace(dtype="float32")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(spec.seed))
     tasks = SyntheticTasks(cfg.vocab_size, seed=spec.seed)
-    params, _ = pretrain(
+    params, losses = pretrain(
         model, params,
         tasks.stream(TASK_CATEGORIES, spec.pretrain_steps, 8, 32,
                      seed=spec.seed + 1), lr=2e-3)
     state = online_mod.init_trainer(model, jax.random.PRNGKey(spec.seed + 7))
-    return cfg, model, params, tasks, state
+    return ModelBundle(cfg, model, params, tasks, state, losses)

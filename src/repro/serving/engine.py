@@ -301,7 +301,7 @@ class ServingEngine:
         # history of per-update training metrics for timeline reports
         self._step_host = int(self.state.step)
         self.train_history: deque = deque(maxlen=1024)
-        self._train_staged = None      # update metrics safe to materialize
+        self._train_staged: list = []  # update metrics safe to materialize
         self._train_fold_note = None   # metrics folded THIS harvest
         self._profile_active = False
         self._profile_left = 0
@@ -547,7 +547,7 @@ class ServingEngine:
             self._note_update_dispatched()
             # legacy sync path: the metrics stay device-resident; the
             # train_telemetry() accessor materializes them off the hot path
-            self._train_staged = (_m, t_disp, self.clock(), step_u)
+            self._train_staged = [(_m, t_disp, self.clock(), step_u)]
 
     def _note_update_dispatched(self) -> None:
         """Advance the host step mirror + schedule-phase gauges — pure host
@@ -1256,32 +1256,24 @@ class ServingEngine:
     def _maybe_profile_start(self):
         """Optional ``jax.profiler`` capture window (``profile_dir``): start
         at the first dispatch, annotate every dispatch as a step, stop after
-        ``profile_steps`` dispatches.  Best-effort — profiler failures never
-        take down serving."""
+        ``profile_steps`` dispatches.  A requested capture that fails
+        raises: a run asked to trace the device must not pass without the
+        trace."""
         if self.profile_dir and not self._profile_active:
-            try:
-                jax.profiler.start_trace(self.profile_dir)
-                self._profile_active = True
-                self._profile_left = max(1, int(self.profile_steps))
-            except Exception:
-                self.profile_dir = None
+            jax.profiler.start_trace(self.profile_dir)
+            self._profile_active = True
+            self._profile_left = max(1, int(self.profile_steps))
         if not self._profile_active:
             return None
-        try:
-            return jax.profiler.StepTraceAnnotation(
-                "superstep", step_num=int(self.stats["dispatches"]))
-        except Exception:
-            return None
+        return jax.profiler.StepTraceAnnotation(
+            "superstep", step_num=int(self.stats["dispatches"]))
 
     def _maybe_profile_stop(self) -> None:
         if not self._profile_active:
             return
         self._profile_left -= 1
         if self._profile_left <= 0:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            jax.profiler.stop_trace()
             self._profile_active = False
             self.profile_dir = None     # window consumed; do not restart
 
@@ -1367,18 +1359,18 @@ class ServingEngine:
             fold_note = (m_dev, t_disp_u, t_fold, step_u)
         if self._inflight is None:
             if fold_note is not None:
-                self._train_staged = fold_note
+                self._train_staged.append(fold_note)
             return []
         res, clock_mark, lanes, t_disp_wall = self._inflight
         self._inflight = None
-        staged = self._train_staged
+        staged, self._train_staged = self._train_staged, []
         t0 = self.clock()
         main, m_host = jax.device_get((
             (res.done, res.gen_count, res.gen_buf, res.lane_blocks,
              res.lane_committed, res.lane_accepted, res.lane_drafted,
              res.k_lane, res.accept_ema, res.k_cool,
              res.accept_hist, res.depth_hist, res.buffer["count"]),
-            staged[0] if staged is not None else None))
+            [note[0] for note in staged]))
         (done_np, cnt_np, gen_np, blocks_np, committed_np, accepted_np,
          drafted_np, k_np, ema_np, cool_np, ahist_np, dhist_np,
          buf_count) = main
@@ -1388,9 +1380,8 @@ class ServingEngine:
         self.telem.h_sync_wait.observe(now - t0)
         if tr is not None:
             tr.span(self.telem.tid_engine, "sync_wait", t0, now)
-        if staged is not None:
-            self._fold_train_metrics(m_host, staged[1], staged[2], staged[3])
-            self._train_staged = None
+        for note, m in zip(staged, m_host):
+            self._fold_train_metrics(m, note[1], note[2], note[3])
         # fold the in-graph per-block histograms (length K_blk+1, which may
         # be below k_max+1 when an adaptive dispatch specialized shallower)
         for i, n in enumerate(ahist_np):
@@ -1505,7 +1496,7 @@ class ServingEngine:
                            args={"step": step_u, "buffer": int(buf_count)},
                            cat="train")
         if fold_note is not None:
-            self._train_staged = fold_note
+            self._train_staged.append(fold_note)
         return outs
 
     def _step_continuous(self) -> List[Completion]:
@@ -1665,11 +1656,10 @@ class ServingEngine:
         ``history``.  Materializes any still-staged update metrics — may
         synchronize with the device, so call OFF the serving hot path
         (between bursts, at shutdown, in benches)."""
-        if self._train_staged is not None:
-            m_dev, t_disp, t_fold, step_u = self._train_staged
-            self._train_staged = None
-            self._fold_train_metrics(jax.device_get(m_dev), t_disp, t_fold,
-                                     step_u)
+        staged, self._train_staged = self._train_staged, []
+        for (_, t_disp, t_fold, step_u), m in zip(
+                staged, jax.device_get([note[0] for note in staged])):
+            self._fold_train_metrics(m, t_disp, t_fold, step_u)
         t = self.telem
         ph = schedule_mod.phase_info(self._step_host, self.model.cfg.dvi)
         return {

@@ -183,3 +183,16 @@ def test_ops_wrappers_jit():
         np.asarray(ops.paged_decode_attention(q, kp, vp, plens, tbl)),
         np.asarray(ops.paged_decode_attention(q, kp, vp, plens, tbl,
                                               impl="ref")), atol=2e-5)
+
+
+def test_ops_refuse_backends_they_cannot_serve(monkeypatch):
+    """Kernels compile on 'tpu', interpret on 'cpu', and refuse any other
+    backend instead of silently interpreting there."""
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._interpret()

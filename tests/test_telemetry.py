@@ -431,3 +431,42 @@ def test_empty_percentiles_have_count_key(backbone):
     assert lat == {"p50_s": 0.0, "p95_s": 0.0, "mean_s": 0.0, "count": 0}
     assert tick["count"] == 0 and tick["p50_s"] == 0.0 \
         and tick["max_s"] == 0.0
+
+
+def test_train_history_keeps_every_update(backbone):
+    """Every folded drafter update lands in the history, including the one
+    folded while no superstep is in flight (the end of each burst), over
+    two bursts with the engine idle in between."""
+    cfg, model, params = backbone
+    state = online.init_trainer(model, jax.random.PRNGKey(3))
+    eng = ServingEngine(model, params, state, scheduler="continuous",
+                        buckets=(16,), num_slots=3, max_new=12, sync_every=2,
+                        learn=True, update_every=2)
+    for burst in range(2):
+        for r in _requests(cfg, 4, seed=10 + burst, max_new=12):
+            r.uid += 100 * burst
+            eng.submit_request(r)
+        eng.run(max_steps=1000)
+    tt = eng.train_telemetry()
+    assert tt["updates"] > 0
+    assert len(tt["history"]) == tt["updates"]
+    assert [h["step"] for h in tt["history"]] == list(range(tt["updates"]))
+    assert eng.stats["host_syncs"] == eng.stats["dispatches"]
+
+
+def test_failed_profile_capture_raises(backbone, monkeypatch, tmp_path):
+    """A requested device-trace capture that cannot start fails the run
+    instead of serving on without the trace."""
+    cfg, model, params = backbone
+
+    def refuse(*_a, **_k):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    state = online.init_trainer(model, jax.random.PRNGKey(3))
+    eng = ServingEngine(model, params, state, scheduler="continuous",
+                        buckets=(16,), num_slots=2, max_new=4, learn=False,
+                        profile_dir=str(tmp_path))
+    eng.submit_request(_requests(cfg, 1)[0])
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        eng.run(max_steps=50)
