@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import buffer as buffer_mod
 from repro.core import losses as losses_mod
+from repro.core import scopes
 from repro.core import spec as spec_mod
 from repro.models.model import Model
 from repro.optim import adamw_init, adamw_update
@@ -50,6 +51,7 @@ def make_update_fn(model: Model, mode: str = "full", lr: float = 1e-3):
     dvi = cfg.dvi
 
     @jax.jit
+    @jax.named_scope(scopes.LEARN_UPDATE)
     def update(params, dvi_params, opt_state, buf, baseline, step, key):
         batch = buffer_mod.sample(buf, key, dvi.batch_size)
         fresh = buffer_mod.fresh_batch(buf, dvi.batch_size) if mode == "full" else None

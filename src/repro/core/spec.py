@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from repro.core import buffer as buffer_mod
 from repro.core import schedule as schedule_mod
+from repro.core import scopes
 from repro.core.lora import draft_logits
 from repro.models import transformer as tfm
 from repro.models.model import Model
@@ -201,43 +202,51 @@ def spec_block_step(model: Model, params: dict, dvi_params: dict,
         else:
             dprobs = jnp.zeros((B, 1), jnp.float32)     # unused placeholder
             d_tok = jnp.argmax(dlog, axis=-1).astype(jnp.int32)
-        cache3 = tfm.commit_cache(cfg, cache2, cands, draft_accept)
+        with jax.named_scope(scopes.COMMIT):
+            cache3 = tfm.commit_cache(cfg, cache2, cands, draft_accept)
         return (cache3, d_tok, k_), (h_k[:, 0], d_tok, dprobs, cands)
 
-    (cache_d, _, key), (hk_s, d_s, dp_s, cand_stack) = jax.lax.scan(
-        draft_iter, (cache, pending, key), None, length=K + 1)
-    hk_blk = jnp.moveaxis(hk_s, 0, 1)                   # (B, K+1, d)
-    d_blk = jnp.moveaxis(d_s, 0, 1)                     # (B, K+1)
+    with jax.named_scope(scopes.DRAFT):
+        (cache_d, _, key), (hk_s, d_s, dp_s, cand_stack) = jax.lax.scan(
+            draft_iter, (cache, pending, key), None, length=K + 1)
+        hk_blk = jnp.moveaxis(hk_s, 0, 1)               # (B, K+1, d)
+        d_blk = jnp.moveaxis(d_s, 0, 1)                 # (B, K+1)
 
     # ---- verify: one deep pass over the h_k block ----
-    cache_v = dict(cache_d, lengths=t0)
-    h_L_blk, cache_v2, deep_cands, _ = model.step(params, hk_blk, cache_v, k, L)
-    vlogits = model.logits(params, h_L_blk)
-    y_star = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)       # (B, K+1)
+    with jax.named_scope(scopes.VERIFY):
+        cache_v = dict(cache_d, lengths=t0)
+        h_L_blk, cache_v2, deep_cands, _ = model.step(params, hk_blk,
+                                                      cache_v, k, L)
+        vlogits = model.logits(params, h_L_blk)
+        y_star = jnp.argmax(vlogits, axis=-1).astype(jnp.int32)   # (B, K+1)
 
-    if sampling:
-        key, sub = jax.random.split(key)
-        vprobs = jax.nn.softmax(vlogits / temperature, axis=-1)
-        dprobs = jnp.moveaxis(dp_s, 0, 1)               # (B, K+1, V)
-        m, correction = rejection_commit(sub, d_blk, dprobs, vprobs,
-                                         k_lane=k_lane)
-    else:
-        matches = (d_blk[:, :K] == y_star[:, :K])
-        if k_lane is not None:
-            matches = matches & (jnp.arange(K)[None, :] < k_lane[:, None])
-        m = jnp.sum(jnp.cumprod(matches.astype(jnp.int32), axis=1), axis=1)
-        correction = None
-    accept = jnp.where(done, 0, m + 1)                  # (B,)
+        if sampling:
+            key, sub = jax.random.split(key)
+            vprobs = jax.nn.softmax(vlogits / temperature, axis=-1)
+            dprobs = jnp.moveaxis(dp_s, 0, 1)           # (B, K+1, V)
+            m, correction = rejection_commit(sub, d_blk, dprobs, vprobs,
+                                             k_lane=k_lane)
+        else:
+            matches = (d_blk[:, :K] == y_star[:, :K])
+            if k_lane is not None:
+                matches = matches & (jnp.arange(K)[None, :]
+                                     < k_lane[:, None])
+            m = jnp.sum(jnp.cumprod(matches.astype(jnp.int32), axis=1),
+                        axis=1)
+            correction = None
+        accept = jnp.where(done, 0, m + 1)              # (B,)
 
-    all_cands = dict(_restack_cands(cand_stack), **deep_cands)
-    cache_new = tfm.commit_cache(cfg, cache_v2, all_cands, accept)
+    with jax.named_scope(scopes.COMMIT):
+        all_cands = dict(_restack_cands(cand_stack), **deep_cands)
+        cache_new = tfm.commit_cache(cfg, cache_v2, all_cands, accept)
 
-    # ---- commit tokens ----
-    ar = jnp.arange(K + 1)
-    y_at_m = correction if sampling else \
-        jnp.take_along_axis(y_star, m[:, None], axis=1)[:, 0]
-    commit_vec = jnp.where(ar[None, :] < m[:, None], d_blk, y_at_m[:, None])
-    new_pending = jnp.where(done, pending, y_at_m)
+        # ---- commit tokens ----
+        ar = jnp.arange(K + 1)
+        y_at_m = correction if sampling else \
+            jnp.take_along_axis(y_star, m[:, None], axis=1)[:, 0]
+        commit_vec = jnp.where(ar[None, :] < m[:, None], d_blk,
+                               y_at_m[:, None])
+        new_pending = jnp.where(done, pending, y_at_m)
     return BlockStep(new_pending, commit_vec, accept, m, cache_new,
                      hk_blk, h_L_blk, d_blk, key)
 
@@ -256,21 +265,22 @@ def log_block_tuples(cfg, buf: dict, step: BlockStep, prev_pending: jax.Array,
         return buf
     B = step.d_blk.shape[0]
     d = cfg.d_model
-    i_idx = jnp.arange(1, K + 1)                        # (K,)
-    lim = jnp.minimum(step.m + 1, K if k_lane is None else k_lane)
-    valid = (~done)[:, None] & (i_idx[None, :] <= lim[:, None])
-    reward = (i_idx[None, :] <= step.m[:, None]).astype(jnp.float32)
-    prev = jnp.concatenate([prev_pending[:, None], step.d_blk[:, :K - 1]],
-                           axis=1) if K > 1 else prev_pending[:, None]
-    return buffer_mod.add_block(
-        buf,
-        step.hk_blk[:, :K].reshape(B * K, d),
-        step.hL_blk[:, :K].reshape(B * K, d),
-        step.d_blk[:, :K].reshape(B * K),
-        reward.reshape(B * K),
-        jnp.broadcast_to(i_idx[None], (B, K)).reshape(B * K),
-        prev.reshape(B * K),
-        valid.reshape(B * K))
+    with jax.named_scope(scopes.LEARN_LOG):
+        i_idx = jnp.arange(1, K + 1)                    # (K,)
+        lim = jnp.minimum(step.m + 1, K if k_lane is None else k_lane)
+        valid = (~done)[:, None] & (i_idx[None, :] <= lim[:, None])
+        reward = (i_idx[None, :] <= step.m[:, None]).astype(jnp.float32)
+        prev = jnp.concatenate([prev_pending[:, None], step.d_blk[:, :K - 1]],
+                               axis=1) if K > 1 else prev_pending[:, None]
+        return buffer_mod.add_block(
+            buf,
+            step.hk_blk[:, :K].reshape(B * K, d),
+            step.hL_blk[:, :K].reshape(B * K, d),
+            step.d_blk[:, :K].reshape(B * K),
+            reward.reshape(B * K),
+            jnp.broadcast_to(i_idx[None], (B, K)).reshape(B * K),
+            prev.reshape(B * K),
+            valid.reshape(B * K))
 
 
 def spec_superstep(model: Model, params: dict, dvi_params: dict,
@@ -349,37 +359,41 @@ def spec_superstep(model: Model, params: dict, dvi_params: dict,
         blk = spec_block_step(model, params, dvi_params, pending, cache,
                               k_spec=K, done=done, temperature=temperature,
                               key=key, k_lane=k if ragged else None)
-        # sequential commit semantics, vectorized: candidate positions are
-        # the accepted prefix that still fits the lane budget; an EOS among
-        # them is written and stops everything after it
-        can = ((ar[None, :] < blk.accept[:, None])
-               & (gen_count[:, None] + ar[None, :] < budget[:, None]))
-        hit_eos = can & (blk.commit_vec == eos_id)
-        eos_before = jnp.cumsum(hit_eos.astype(jnp.int32), axis=1) \
-            - hit_eos.astype(jnp.int32)
-        written = can & (eos_before == 0)
-        dest = jnp.where(written,
-                         lane[:, None] * cap + gen_count[:, None] + ar[None, :],
-                         B * cap)                           # OOB -> dropped
-        gen_buf = gen_buf.reshape(-1).at[dest.reshape(-1)].set(
-            blk.commit_vec.reshape(-1), mode="drop").reshape(B, cap)
-        new_count = gen_count + written.sum(axis=1, dtype=jnp.int32)
-        new_done = done | jnp.any(hit_eos, axis=1) | (new_count >= budget)
         if collect:
             buf = log_block_tuples(cfg, buf, blk, pending, done, k_spec=K,
                                    k_lane=k if ragged else None)
-        drafted = drafted + k * live     # depth the block actually ran at
-        # telemetry histograms, in-graph and UNCONDITIONAL (telemetry on/off
-        # shares one compiled graph): per live block, bucket the verifier's
-        # accepted-draft count m and the depth k the block ran at.  Rides
-        # the superstep's existing host sync — zero extra device round-trips
-        a_hist = a_hist.at[blk.m].add(live, mode="drop")
-        d_hist = d_hist.at[k].add(live, mode="drop")
-        if depth_cfg is not None:
-            # controller sees THIS block's outcome (depth k, accepted m) and
-            # adjusts for the next block; masked lanes keep frozen state
-            k, ema, cool = schedule_mod.depth_update(
-                depth_cfg, k, ema, cool, blk.m, ~done, k_hi=khi)
+        with jax.named_scope(scopes.COMMIT):
+            # sequential commit semantics, vectorized: candidate positions
+            # are the accepted prefix that still fits the lane budget; an
+            # EOS among them is written and stops everything after it
+            can = ((ar[None, :] < blk.accept[:, None])
+                   & (gen_count[:, None] + ar[None, :] < budget[:, None]))
+            hit_eos = can & (blk.commit_vec == eos_id)
+            eos_before = jnp.cumsum(hit_eos.astype(jnp.int32), axis=1) \
+                - hit_eos.astype(jnp.int32)
+            written = can & (eos_before == 0)
+            dest = jnp.where(written,
+                             lane[:, None] * cap + gen_count[:, None]
+                             + ar[None, :],
+                             B * cap)                       # OOB -> dropped
+            gen_buf = gen_buf.reshape(-1).at[dest.reshape(-1)].set(
+                blk.commit_vec.reshape(-1), mode="drop").reshape(B, cap)
+            new_count = gen_count + written.sum(axis=1, dtype=jnp.int32)
+            new_done = done | jnp.any(hit_eos, axis=1) | (new_count >= budget)
+            drafted = drafted + k * live  # depth the block actually ran at
+            # telemetry histograms, in-graph and UNCONDITIONAL (telemetry
+            # on/off shares one compiled graph): per live block, bucket the
+            # verifier's accepted-draft count m and the depth k the block
+            # ran at.  Rides the superstep's existing host sync — zero
+            # extra device round-trips
+            a_hist = a_hist.at[blk.m].add(live, mode="drop")
+            d_hist = d_hist.at[k].add(live, mode="drop")
+            if depth_cfg is not None:
+                # controller sees THIS block's outcome (depth k, accepted
+                # m) and adjusts for the next block; masked lanes keep
+                # frozen state
+                k, ema, cool = schedule_mod.depth_update(
+                    depth_cfg, k, ema, cool, blk.m, ~done, k_hi=khi)
         return (i + 1, blk.pending, new_done, gen_buf, new_count,
                 blocks + live, committed + blk.accept,
                 accepted + blk.m * live, drafted,

@@ -138,6 +138,7 @@ import numpy as np
 
 from repro.core import online as online_mod
 from repro.core import schedule as schedule_mod
+from repro.core import scopes
 from repro.core import spec as spec_mod
 from repro.models import transformer as tfm
 from repro.models.model import Model
@@ -218,7 +219,7 @@ class ServingEngine:
     telemetry: bool = False       # lifecycle tracer on (metrics always on)
     trace_limit: int = 200_000    # tracer event cap (overflow -> dropped)
     profile_dir: Optional[str] = None  # jax.profiler capture dir (optional)
-    profile_steps: int = 32       # dispatches inside the capture window
+    profile_steps: int = 32       # ticks inside the capture window
     max_queue: int = 0            # admission queue bound (0 = unbounded);
                                   # submissions past it raise QueueFull
     tenant_weights: Optional[Dict[str, float]] = None  # WFQ shares (def. 1)
@@ -397,6 +398,7 @@ class ServingEngine:
                                  f"attention stack; got segment kinds {bad}")
         self._evict_seen = 0          # pool eviction counter folded per tick
 
+        @jax.named_scope(scopes.PREFILL_ADMIT)
         def admit(params, cache, pending, prompt, slot):
             _, pc, _ = model.prefill(params, prompt[None, :-1], max_len=cap)
             cache = tfm.insert_slot(cfg, cache, pc, slot)
@@ -405,6 +407,7 @@ class ServingEngine:
             return pending, cache
         self._admit_fn = jax.jit(admit)
 
+        @jax.named_scope(scopes.PREFILL_ADMIT)
         def admit_paged(params, cache, pending, prompt, slot, row):
             cache = tfm.map_slot_pages(cache, slot, row)
             # prefill scratch is prompt-sized, not worst-case-sized: the
@@ -417,6 +420,7 @@ class ServingEngine:
             return pending, cache
         self._admit_paged_fn = jax.jit(admit_paged)
 
+        @jax.named_scope(scopes.PREFILL_ADMIT)
         def admit_prefix(cache, pending, slot, row, length, cow_src, cow_dst,
                          tok, live):
             # warm admission (prefix-cache hit): the lane's cached prefix is
@@ -435,6 +439,7 @@ class ServingEngine:
             return pending, cache
         self._admit_prefix_fn = jax.jit(admit_prefix)
 
+        @jax.named_scope(scopes.PREFILL_ADMIT)
         def admit_chunk(params, cache, chunk, slot):
             # chunked admission (contiguous): prefill ONLY the first chunk
             # into a chunk-sized scratch — admission device work is O(chunk),
@@ -445,6 +450,7 @@ class ServingEngine:
             return tfm.insert_slot(cfg, cache, pc, slot)
         self._admit_chunk_fn = jax.jit(admit_chunk)
 
+        @jax.named_scope(scopes.PREFILL_CHUNK)
         def chunk_step(params, cache, pending, tokens, take, finish_tok,
                        finished):
             # ONE batched prefill-chunk step: every prefilling lane advances
@@ -469,7 +475,8 @@ class ServingEngine:
                 return b
         return self.buckets[-1]
 
-    def submit_request(self, req: Request) -> RequestHandle:
+    def submit_request(self, req: Request,
+                       t_arrive: Optional[float] = None) -> RequestHandle:
         """Accept `req` into the admission queue and return its handle.
 
         The handle is the caller's async view: ``deltas()`` streams
@@ -479,7 +486,12 @@ class ServingEngine:
         (``max_queue``) and full, the submission is REJECTED: the
         ``rejected`` counter increments, the returned-would-be handle is
         finished with outcome ``"rejected"``, and ``QueueFull`` is raised
-        (it carries the handle as ``exc.handle``)."""
+        (it carries the handle as ``exc.handle``).
+
+        ``t_arrive`` (engine clock): when the request reached a front end
+        that handed it over from another thread.  With the tracer on, the
+        request's lifecycle then starts there, with a ``submit`` phase up
+        to this call."""
         now = self.clock()
         h = RequestHandle(req.uid, getattr(req, "tenant", "default"),
                           int(getattr(req, "priority", 0)), clock=self.clock)
@@ -504,10 +516,14 @@ class ServingEngine:
         self._submit_t[req.uid] = now
         tr = self.telem.tracer
         if tr is not None and self.scheduler == "continuous":
-            tr.async_begin("request", req.uid, now,
+            t_in = now if t_arrive is None else t_arrive
+            tr.async_begin("request", req.uid, t_in,
                            args={"prompt_len": int(len(req.prompt)),
                                  "max_new": int(req.max_new),
                                  "tenant": h.tenant})
+            if t_arrive is not None:
+                tr.async_begin("submit", req.uid, t_arrive)
+                tr.async_end("submit", req.uid, now)
             tr.async_begin("queued", req.uid, now)
         if self.scheduler == "continuous":
             self.telem.g_queue.set(len(self._tq))
@@ -1135,7 +1151,7 @@ class ServingEngine:
                 break
         if dirty:
             self._cache = self._set_tbl_fn(self._cache,
-                                           jnp.asarray(self._tbl_host))
+                                           jnp.array(self._tbl_host))
 
     def _sync_row(self, s: int, uid: int) -> None:
         """Mirror lane `s`'s pool ownership into the host block table
@@ -1214,7 +1230,7 @@ class ServingEngine:
                 finish_tok[s] = st.pf_prompt[-1]
         if dirty:
             self._cache = self._set_tbl_fn(self._cache,
-                                           jnp.asarray(self._tbl_host))
+                                           jnp.array(self._tbl_host))
         if not take.any() and not finished.any():
             return
         t_c0 = self.clock()
@@ -1255,10 +1271,9 @@ class ServingEngine:
 
     def _maybe_profile_start(self):
         """Optional ``jax.profiler`` capture window (``profile_dir``): start
-        at the first dispatch, annotate every dispatch as a step, stop after
-        ``profile_steps`` dispatches.  A requested capture that fails
-        raises: a run asked to trace the device must not pass without the
-        trace."""
+        at the first tick, annotate every tick as a step, stop after
+        ``profile_steps`` ticks.  A requested capture that fails raises: a
+        run asked to trace the device must not pass without the trace."""
         if self.profile_dir and not self._profile_active:
             jax.profiler.start_trace(self.profile_dir)
             self._profile_active = True
@@ -1266,7 +1281,8 @@ class ServingEngine:
         if not self._profile_active:
             return None
         return jax.profiler.StepTraceAnnotation(
-            "superstep", step_num=int(self.stats["dispatches"]))
+            "tick", step_num=max(1, int(self.profile_steps))
+            - self._profile_left)
 
     def _maybe_profile_stop(self) -> None:
         if not self._profile_active:
@@ -1285,9 +1301,6 @@ class ServingEngine:
         for s, st in enumerate(self._slots):
             if st is not None:
                 budget[s] = st.max_new - len(st.gen)
-        ann = self._maybe_profile_start()
-        if ann is not None:
-            ann.__enter__()
         if self._depth is not None:
             # per-lane depth ceiling = what growth provisioned pages for;
             # the draft-scan width K_blk is the max ceiling over lanes that
@@ -1301,20 +1314,20 @@ class ServingEngine:
                 if st is not None and st.pf_pos is None:
                     kcap[s] = self._lane_growth_k(s)
                     kblk = max(kblk, int(kcap[s]))
+            # host mirrors go in as copies (jnp.array): on the CPU backend
+            # jnp.asarray may alias a numpy buffer, and admission rewrites
+            # these mirrors while this superstep is still queued
             res = self._superstep_adaptive_fn(
                 self.params, self.state.dvi_params, self._pending,
-                self._cache, self.state.buf, jnp.asarray(self._done),
-                jnp.asarray(budget), jnp.asarray(self._k_host),
-                jnp.asarray(self._ema_host), jnp.asarray(self._cool_host),
+                self._cache, self.state.buf, jnp.array(self._done),
+                jnp.asarray(budget), jnp.array(self._k_host),
+                jnp.array(self._ema_host), jnp.array(self._cool_host),
                 jnp.asarray(kcap), kblk)
         else:
             res = self._superstep_fn(self.params, self.state.dvi_params,
                                      self._pending, self._cache,
-                                     self.state.buf, jnp.asarray(self._done),
+                                     self.state.buf, jnp.array(self._done),
                                      jnp.asarray(budget))
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        self._maybe_profile_stop()
         # engine state advances to the (not yet materialized) outputs; every
         # follow-up device op (admission, reset, next superstep) chains on
         # them without a host round-trip
@@ -1361,25 +1374,37 @@ class ServingEngine:
             if fold_note is not None:
                 self._train_staged.append(fold_note)
             return []
-        res, clock_mark, lanes, t_disp_wall = self._inflight
-        self._inflight = None
+        inflight, self._inflight = self._inflight, None
+        res = inflight[0]
         staged, self._train_staged = self._train_staged, []
         t0 = self.clock()
-        main, m_host = jax.device_get((
-            (res.done, res.gen_count, res.gen_buf, res.lane_blocks,
-             res.lane_committed, res.lane_accepted, res.lane_drafted,
-             res.k_lane, res.accept_ema, res.k_cool,
-             res.accept_hist, res.depth_hist, res.buffer["count"]),
-            [note[0] for note in staged]))
-        (done_np, cnt_np, gen_np, blocks_np, committed_np, accepted_np,
-         drafted_np, k_np, ema_np, cool_np, ahist_np, dhist_np,
-         buf_count) = main
+        fetched = self._phase(
+            "sync_wait", jax.device_get, (
+                (res.done, res.gen_count, res.gen_buf, res.lane_blocks,
+                 res.lane_committed, res.lane_accepted, res.lane_drafted,
+                 res.k_lane, res.accept_ema, res.k_cool,
+                 res.accept_hist, res.depth_hist, res.buffer["count"]),
+                [note[0] for note in staged]),
+            label="dvi.tick.harvest.sync_wait")
         now = self.clock()
         self.stats["host_syncs"] += 1
         self.stats["sync_wait_s"] += now - t0
         self.telem.h_sync_wait.observe(now - t0)
-        if tr is not None:
-            tr.span(self.telem.tid_engine, "sync_wait", t0, now)
+        return self._phase("fold", self._fold, inflight, fetched, staged,
+                           now, fold_note, label="dvi.tick.harvest.fold")
+
+    def _fold(self, inflight: tuple, fetched: tuple, staged: list,
+              now: float, fold_note: Optional[tuple]) -> List[Completion]:
+        """Fold a harvested superstep's fetched summary (and the staged
+        drafter-update metrics) into host bookkeeping: stream committed
+        tokens, retire finished lanes, maybe dispatch the next drafter
+        update."""
+        tr = self.telem.tracer
+        _, clock_mark, lanes, t_disp_wall = inflight
+        main, m_host = fetched
+        (done_np, cnt_np, gen_np, blocks_np, committed_np, accepted_np,
+         drafted_np, k_np, ema_np, cool_np, ahist_np, dhist_np,
+         buf_count) = main
         for note, m in zip(staged, m_host):
             self._fold_train_metrics(m, note[1], note[2], note[3])
         # fold the in-graph per-block histograms (length K_blk+1, which may
@@ -1499,9 +1524,39 @@ class ServingEngine:
             self._train_staged.append(fold_note)
         return outs
 
+    def _phase(self, name: str, fn, *a, label: Optional[str] = None):
+        """``fn(*a)``; with the tracer on, inside a phase span ``name`` on
+        the engine track that is also the profiler annotation ``label``
+        (``dvi.tick.<name>`` by default)."""
+        tr = self.telem.tracer
+        if tr is None:
+            return fn(*a)
+        with tr.phase(self.telem.tid_engine, name,
+                      label or f"dvi.tick.{name}"):
+            return fn(*a)
+
     def _step_continuous(self) -> List[Completion]:
-        """One tick: pre-admit arrivals into already-free lanes (their
-        prefill dispatches queue behind the in-flight superstep — host work
+        """One tick (``_tick``).  With ``profile_dir`` set, the tick is a
+        step of the profiler's capture window; with the tracer on, it is
+        the profiler annotation ``dvi.tick``."""
+        step = self._maybe_profile_start()
+        if step is None:
+            return self._annotated_tick()
+        try:
+            with step:
+                return self._annotated_tick()
+        finally:
+            self._maybe_profile_stop()
+
+    def _annotated_tick(self) -> List[Completion]:
+        if self.telem.tracer is None:
+            return self._tick()
+        with jax.profiler.TraceAnnotation("dvi.tick"):
+            return self._tick()
+
+    def _tick(self) -> List[Completion]:
+        """Pre-admit arrivals into already-free lanes (their prefill
+        dispatches queue behind the in-flight superstep — host work
         overlaps device compute), harvest the in-flight superstep, retire
         finished lanes, grow paged lanes (preempting if the pool runs dry),
         admit into freshly freed lanes, advance mid-prefill lanes by one
@@ -1509,44 +1564,34 @@ class ServingEngine:
         self._tick_t0 = tick0 = self.clock()
         tr = self.telem.tracer
         tid_e = self.telem.tid_engine if tr is not None else 0
-
-        def _phase(name, fn, *a):
-            if tr is None:
-                return fn(*a)
-            p0 = self.clock()
-            try:
-                return fn(*a)
-            finally:
-                tr.span(tid_e, name, p0, self.clock())
-
         try:
             # pre-admission reserves the live lanes' worst-case growth
             # demand (paged): a new request must not grab pages this tick's
             # growth pass would claw back by preempting the admitted lane
-            _phase("pre_admit", self._admit_waiting,
-                   self._growth_reserve() if self.paged else 0)
-            outs = _phase("harvest", self._harvest)
+            self._phase("pre_admit", self._admit_waiting,
+                        self._growth_reserve() if self.paged else 0)
+            outs = self._phase("harvest", self._harvest)
             # cancellation boundary: the harvest just retired the in-flight
             # superstep, so lanes can be torn down without racing device
             # reads of their pages; queued cancels drop out of the tenant
             # queue before this tick's growth/admission see them
-            _phase("sweep_cancels", self._sweep_cancels)
+            self._phase("sweep_cancels", self._sweep_cancels)
             # grow BEFORE admitting: admission then sees the true residual
             # capacity, instead of grabbing pages that live lanes
             # immediately claw back by preempting the just-admitted lane.
             # Mid-prefill lanes' imminent chunk demand stays reserved even
             # here: _advance_prefill consumes it right after this admission.
             if self.paged:
-                _phase("grow_pages", self._grow_pages)
-            _phase("admit", self._admit_waiting,
-                   self._prefill_reserve() if self.paged else 0)
+                self._phase("grow_pages", self._grow_pages)
+            self._phase("admit", self._admit_waiting,
+                        self._prefill_reserve() if self.paged else 0)
             # chunked prefill interleaves with supersteps: one bounded
             # chunk step per tick, then the superstep over decoding lanes
             # (lanes whose prefill finished this tick included)
-            _phase("prefill_chunk", self._advance_prefill)
+            self._phase("prefill_chunk", self._advance_prefill)
             if any(st is not None and st.pf_pos is None
                    for st in self._slots):
-                _phase("dispatch", self._dispatch_superstep)
+                self._phase("dispatch", self._dispatch_superstep)
         finally:
             dt = self.clock() - self._tick_t0
             self._clock += dt
@@ -1568,7 +1613,8 @@ class ServingEngine:
             if tr is not None:
                 tr.span(tid_e, "tick", tick0, tick0 + dt,
                         args={"live": self.active_slots,
-                              "queued": len(self._tq)})
+                              "queued": len(self._tq),
+                              "annotation": "dvi.tick"})
             self._tick_t0 = None
         return outs
 

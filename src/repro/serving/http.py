@@ -159,26 +159,53 @@ class EngineDriver:
     def next_uid(self) -> int:
         return next(self._uids)
 
-    def submit(self, req: Request, timeout: float = 120.0) -> RequestHandle:
-        return self.call(lambda: self.engine.submit_request(req), timeout)
+    def submit(self, req: Request, timeout: float = 120.0,
+               t_arrive: Optional[float] = None) -> RequestHandle:
+        """Submit on the engine thread.  ``t_arrive`` (the engine's clock):
+        when the request reached the caller, for the tracer's ``submit``
+        phase."""
+        return self.call(lambda: self.engine.submit_request(req, t_arrive),
+                         timeout)
 
     # -- engine thread --------------------------------------------------
 
     def _drain_inbox(self) -> None:
         with self._lock:
             batch, self._inbox = self._inbox, []
+        if not batch:
+            return
+        tr = self.engine.telem.tracer
+        if tr is None:
+            self._run_calls(batch)
+            return
+        with tr.phase(self.engine.telem.tid_engine, "driver.inbox",
+                      "dvi.driver.inbox", args={"calls": len(batch)}):
+            self._run_calls(batch)
+
+    @staticmethod
+    def _run_calls(batch: list) -> None:
         for fn, fut in batch:
             try:
                 fut.set_result(fn())
             except BaseException as e:          # marshalled to the caller
                 fut.set_exception(e)
 
+    def _idle(self) -> None:
+        """Park until woken or ``poll_s`` passes."""
+        tr = self.engine.telem.tracer
+        if tr is None:
+            self._wake.wait(self.poll_s)
+            return
+        with tr.phase(self.engine.telem.tid_engine, "driver.idle",
+                      "dvi.driver.idle"):
+            self._wake.wait(self.poll_s)
+
     def _loop(self) -> None:
         try:
             while not self._stopping:
                 self._drain_inbox()
                 if self._paused or not self.engine.busy:
-                    self._wake.wait(self.poll_s)
+                    self._idle()
                     self._wake.clear()
                     continue
                 self.engine.step()
@@ -276,10 +303,12 @@ class ApiHandler(BaseHTTPRequestHandler):
             self._error(404, f"no route {self.path!r}")
 
     def do_POST(self):
+        driver: EngineDriver = self.server.driver
+        tr = driver.engine.telem.tracer
+        t_arrive = tr.now() if tr is not None else None
         if self.path != "/v1/completions":
             self._error(404, f"no route {self.path!r}")
             return
-        driver: EngineDriver = self.server.driver
         try:
             n = int(self.headers.get("Content-Length", 0))
             body = json.loads(self.rfile.read(n) or b"{}")
@@ -294,7 +323,7 @@ class ApiHandler(BaseHTTPRequestHandler):
                       tenant=str(body.get("user", "default")),
                       priority=int(body.get("priority", 0)))
         try:
-            handle = driver.submit(req)
+            handle = driver.submit(req, t_arrive=t_arrive)
         except QueueFull as e:
             self._error(429, str(e), "rate_limit_exceeded")
             return
@@ -344,11 +373,17 @@ class ApiHandler(BaseHTTPRequestHandler):
             self.wfile.flush()
 
         sent = []
+        tr = self.server.driver.engine.telem.tracer
         try:
             for chunk in handle.deltas(
                     timeout=self.server.request_timeout_s):
+                first = not sent
                 sent.extend(chunk)
                 send(_chunk_payload(rid, model, chunk, None))
+                if first and tr is not None:
+                    # relay: the first tokens' feed -> their SSE write
+                    tr.async_begin("relay", handle.uid, handle.t_first_token)
+                    tr.async_end("relay", handle.uid)
             send(_chunk_payload(rid, model, [],
                                 self._finish_reason(handle, sent)))
             self.wfile.write(b"data: [DONE]\n\n")

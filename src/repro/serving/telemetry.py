@@ -133,7 +133,10 @@ import math
 import time
 from collections import deque
 from collections.abc import MutableMapping
-from typing import Callable, Dict, List, Optional, Sequence
+from contextlib import contextmanager
+from typing import Callable, Dict, Iterator, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 
 # ---------------------------------------------------------------------------
@@ -486,13 +489,21 @@ class Tracer:
     are microseconds on the injected monotonic clock, zeroed at tracer
     construction.  ``span`` appends a complete ``ph="X"`` event (events
     may be appended out of order — viewers sort by ts), ``instant`` a
-    point event.  The event list is capped; overflow increments
-    ``dropped`` instead of growing without bound."""
+    point event, ``phase`` a span that is also a profiler annotation.
+    The event list is capped; overflow increments ``dropped`` instead of
+    growing without bound.
+
+    ``anchor`` pairs the clock's zero with the wall clock in nanoseconds
+    since the epoch, read together at construction.  The wall clock is the
+    profiler's (a capture's ``profile_start_time`` plus an event's offset),
+    so an event at ``ts`` µs lies at ``anchor[1] + ts * 1e3`` ns on a
+    device trace's timeline."""
 
     def __init__(self, clock: Callable[[], float] = time.monotonic,
                  process: str = "dvi-serving", limit: int = 200_000):
         self._clock = clock
         self._t0 = clock()
+        self.anchor = (self._t0, time.time_ns())
         self._limit = limit
         self.dropped = 0
         self.events: List[dict] = [
@@ -522,6 +533,25 @@ class Tracer:
                     "dur": max(self._ts(t1) - self._ts(t0), 0.0),
                     "args": args or {}})
 
+    @contextmanager
+    def phase(self, tid: int, name: str, label: str,
+              args: Optional[dict] = None,
+              cat: str = "serving") -> Iterator[dict]:
+        """One span, two sinks: a complete event ``name`` on track ``tid``
+        on this tracer's clock, and a ``jax.profiler.TraceAnnotation``
+        ``label`` on the profiler's, over the same ``with`` body.  The event
+        names its annotation in ``args["annotation"]``: a capture records
+        no annotation that began before it, and the event (placed through
+        ``anchor``) stands in.  Yields the event's ``args``, which the body
+        may fill."""
+        args = dict(args or {}, annotation=label)
+        with TraceAnnotation(label):
+            t0 = self.now()
+            try:
+                yield args
+            finally:
+                self.span(tid, name, t0, self.now(), args, cat)
+
     def instant(self, tid: int, name: str, t: Optional[float] = None,
                 args: Optional[dict] = None, cat: str = "serving"):
         self._emit({"name": name, "ph": "i", "pid": 0, "tid": tid,
@@ -550,7 +580,9 @@ class Tracer:
 
     def to_dict(self) -> dict:
         return {"traceEvents": list(self.events), "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": self.dropped}}
+                "otherData": {"dropped_events": self.dropped,
+                              "clock_anchor": {"clock_s": self.anchor[0],
+                                               "wall_ns": self.anchor[1]}}}
 
     def write(self, path: str):
         with open(path, "w") as f:

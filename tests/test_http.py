@@ -209,3 +209,43 @@ def test_backpressure_returns_429(server):
     line = next(l for l in body.decode().splitlines()
                 if l.startswith("dvi_serving_rejected_total"))
     assert float(line.split()[-1]) >= 2
+
+
+def test_submit_and_relay_spans(server):
+    """With the tracer on, a streamed request's timeline holds its hand-offs
+    between the HTTP thread and the engine thread: ``submit`` (handler entry
+    -> engine submission) and ``relay`` (first tokens fed -> their SSE
+    chunk written), each inside the request's own span."""
+    from repro.serving.telemetry import validate_trace
+    _, base, cfg = server
+    state = online.init_trainer(base.model, jax.random.PRNGKey(3))
+    eng = ServingEngine(base.model, base.params, state,
+                        scheduler="continuous", num_slots=2, max_new=32,
+                        buckets=(16,), learn=False, telemetry=True)
+    srv = make_server("127.0.0.1", 0, eng, model_id="dvi-tiny",
+                      request_timeout_s=120.0)
+    th = threading.Thread(target=srv.serve_forever,
+                          kwargs={"poll_interval": 0.05}, daemon=True)
+    th.start()
+    try:
+        _, r = _post(srv, {"prompt": _prompt(cfg, seed=9),
+                           "max_tokens": 24, "stream": True})
+        toks, _ = _read_sse(r)
+        trace = srv.driver.call(eng.trace_dict)
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        srv.driver.stop(drain=True)
+        th.join(timeout=30.0)
+    assert len(toks) == 24
+    validate_trace(trace)
+    pairs = {}
+    for e in trace["traceEvents"]:
+        if e["ph"] in ("b", "e"):
+            pairs.setdefault(e["name"], {})[e["ph"]] = (e["id"], e["ts"])
+    (uid, _), = {pairs["request"]["b"]}
+    at = {n: {ph: pairs[n][ph][1] for ph in "be"}
+          for n in ("request", "submit", "relay")}
+    assert all(pairs[n][ph][0] == uid for n in at for ph in "be")
+    assert at["request"]["b"] == at["submit"]["b"] <= at["submit"]["e"] \
+        <= at["relay"]["b"] <= at["relay"]["e"] <= at["request"]["e"]

@@ -139,3 +139,36 @@ def test_sync_engine_short_batch_stats(backbone):
         assert eng4.stats[k] == eng3.stats[k], k
     assert int(eng4.state.buf["count"]) == int(eng3.state.buf["count"])
     assert all(isinstance(o, Completion) and o.latency_s > 0 for o in outs4)
+
+
+@pytest.mark.parametrize("adaptive", [False, True])
+def test_dispatch_copies_the_host_mirrors(backbone, adaptive):
+    """The superstep and the block-table push take copies of the engine's
+    host mirrors: admission rewrites them while that work is still queued,
+    and on the CPU backend ``jnp.asarray`` would alias the numpy buffer."""
+    cfg, model, params = backbone
+    state = online.init_trainer(model, jax.random.PRNGKey(3))
+    eng = ServingEngine(model, params, state, scheduler="continuous",
+                        num_slots=3, max_new=8, buckets=(16,), sync_every=2,
+                        kv_pages=40, kv_page_size=4, cache_len=40,
+                        adaptive_k=adaptive)
+    seen = []
+    attr = "_superstep_adaptive_fn" if adaptive else "_superstep_fn"
+    tbl_fn, step_fn = eng._set_tbl_fn, getattr(eng, attr)
+
+    def step(*a):
+        seen.append([(x, np.asarray(x).copy()) for x in a[5:6] + a[7:10]])
+        return step_fn(*a)
+
+    def set_tbl(cache, tbl):
+        seen.append([(tbl, np.asarray(tbl).copy())])
+        return tbl_fn(cache, tbl)
+    setattr(eng, attr, step)
+    eng._set_tbl_fn = set_tbl
+    for r in _ragged_requests(cfg, 5):
+        eng.submit_request(r)
+    eng.run(max_steps=500)
+    assert len(seen) > 2
+    for args in seen:                  # the mirrors moved on; the inputs
+        for x, at_call in args:        # the device got did not
+            np.testing.assert_array_equal(np.asarray(x), at_call)

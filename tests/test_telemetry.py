@@ -5,6 +5,7 @@ leaves committed token streams bit-identical (the in-graph histograms are
 computed unconditionally, so telemetry on/off shares one compiled graph,
 and every host-side observation rides the harvest's single device_get)."""
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -314,6 +315,11 @@ def test_telemetry_on_off_bit_identity(backbone, kv_pages, sync_every):
 
     trace = on_eng.trace_dict()
     validate_trace(trace)
+    # the harvest's device wait and its fold are phases of their own (nested
+    # in the harvest's, checked above): one of each per host sync
+    phases = [e["name"] for e in trace["traceEvents"] if e["ph"] == "X"]
+    assert phases.count("sync_wait") == phases.count("fold") == \
+        on_eng.stats["host_syncs"]
     assert off_eng.trace_dict() is None
     with pytest.raises(ValueError):
         off_eng.write_trace("/dev/null")
@@ -470,3 +476,100 @@ def test_failed_profile_capture_raises(backbone, monkeypatch, tmp_path):
     eng.submit_request(_requests(cfg, 1)[0])
     with pytest.raises(RuntimeError, match="profiler unavailable"):
         eng.run(max_steps=50)
+
+
+# ---------------------------------------------------------------------------
+# device scopes and host spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def test_lowered_programs_carry_the_scopes(backbone):
+    """The superstep, chunk-step, admission and drafter-update programs name
+    their parts: the lowered text holds op names under every scope."""
+    from repro.core import scopes
+    cfg, model, params = backbone
+    state = online.init_trainer(model, jax.random.PRNGKey(3))
+    eng = ServingEngine(model, params, state, scheduler="continuous",
+                        buckets=(16,), num_slots=2, max_new=8, sync_every=2,
+                        kv_pages=40, kv_page_size=4, cache_len=40,
+                        prefill_chunk=4, learn=True, update_every=1)
+    want = {"_superstep_fn": (scopes.DRAFT, scopes.VERIFY, scopes.COMMIT,
+                              scopes.LEARN_LOG),
+            "_chunk_fn": (scopes.PREFILL_CHUNK,),
+            "_admit_paged_fn": (scopes.PREFILL_ADMIT,),
+            "_update_fn": (scopes.LEARN_UPDATE,)}
+    called = {}
+    for attr in want:
+        def record(*a, _f=getattr(eng, attr), _attr=attr):
+            called.setdefault(_attr, (_f, a))
+            return _f(*a)
+        setattr(eng, attr, record)
+    for r in _requests(cfg, 3, seed=1, max_new=8):
+        eng.submit_request(r)
+    eng.run(max_steps=500)
+    assert set(called) == set(want)
+    for attr, names in want.items():
+        f, a = called[attr]
+        text = f.lower(*a).as_text(debug_info=True)
+        for name in names:
+            assert f"/{name}/" in text, (attr, name)
+    assert {n for v in want.values() for n in v} == set(scopes.ALL)
+
+
+# the profiler annotation of each engine-track phase span
+ANNOTATION = {"tick": "dvi.tick", "harvest": "dvi.tick.harvest",
+              "sync_wait": "dvi.tick.harvest.sync_wait",
+              "fold": "dvi.tick.harvest.fold",
+              "dispatch": "dvi.tick.dispatch",
+              "driver.inbox": "dvi.driver.inbox",
+              "driver.idle": "dvi.driver.idle"}
+
+
+def test_program_spans_on_the_profiler_clock(backbone, tmp_path):
+    """A profiler capture holds the tick's and the driver's annotations,
+    and each phase span of the tracer, placed on the wall clock through the
+    tracer's anchor, starts within 1 ms of its annotation.  Its end is held
+    to 1 ms or 5 % of its length: the profiler's clock interpolates between
+    readings of the kernel's, and on a loaded machine it ran 4 ms short
+    over a 226 ms tick."""
+    from jax.profiler import ProfileData
+    from repro.serving.http import EngineDriver
+    cfg, model, params = backbone
+    state = online.init_trainer(model, jax.random.PRNGKey(3))
+    eng = ServingEngine(model, params, state, scheduler="continuous",
+                        buckets=(16,), num_slots=2, max_new=8, sync_every=2,
+                        learn=False, telemetry=True)
+    drv = EngineDriver(eng).start()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        hs = [drv.submit(r) for r in _requests(cfg, 3, seed=7, max_new=8)]
+        for h in hs:
+            h.result(timeout=120)
+        time.sleep(0.1)                        # the driver idles
+    finally:
+        # the engine thread ends inside the capture, so that the profiler
+        # collects its last annotations
+        drv.stop(drain=True)
+        jax.profiler.stop_trace()
+    data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    env = dict(data.find_plane_with_name("Task Environment").stats)
+    lo, hi = int(env["profile_start_time"]), int(env["profile_stop_time"])
+    ann = [(ev.name, lo + ev.start_ns, lo + ev.start_ns + ev.duration_ns)
+           for plane in data.planes if plane.name.startswith("/host:")
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith("dvi.")]
+    assert set(ANNOTATION.values()) <= {n for n, _, _ in ann}
+    tr = eng.telem.tracer
+    checked = 0
+    for e in tr.events:
+        if e["ph"] != "X" or e["name"] not in ANNOTATION:
+            continue
+        w0 = tr.anchor[1] + e["ts"] * 1e3
+        w1 = w0 + e["dur"] * 1e3
+        if not lo + 2e6 < w0 < w1 < hi - 2e6:
+            continue
+        near = min((abs(a - w0) + abs(b - w1), a, b) for n, a, b in ann
+                   if n == ANNOTATION[e["name"]])
+        assert abs(near[1] - w0) < 1e6, e
+        assert abs(near[2] - w1) < max(1e6, 0.05 * (w1 - w0)), e
+        checked += 1
+    assert checked >= 10
