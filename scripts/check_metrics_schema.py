@@ -77,6 +77,7 @@ REQUIRED = {
     "dvi_serving_host_syncs_total": "counter",
     "dvi_serving_sync_wait_seconds_total": "counter",
     "dvi_serving_dispatches_total": "counter",
+    "dvi_serving_kv_pool_donations_total": "counter",
     "dvi_serving_prefill_chunks_total": "counter",
     "dvi_serving_prefill_tokens_total": "counter",
     "dvi_serving_kv_watermark_hits_total": "counter",

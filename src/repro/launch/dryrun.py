@@ -157,7 +157,9 @@ def build_case(cfg: ModelConfig, shape: InputShape, mesh):
             model, params, dvi_params, pending, cache)
         return y, commit_vec, accept, shd.constrain_cache_tree(cfg, cache2)
 
-    fn.donate = (3,)       # cache updates in place (real serving aliases it)
+    # the cache updates in place, as the serving engine's admission, chunk,
+    # block-table and reset programs take it (its supersteps copy it once)
+    fn.donate = (3,)
     args = [param_shapes, dvi_shapes, pending, cache_shapes]
     shards = [p_shard, dvi_shard, pend_shard, c_shard]
     return fn, args, shards
@@ -267,6 +269,7 @@ def run_case(arch: str, shape_name: str, multi_pod: bool,
             "argument_bytes": ma.argument_size_in_bytes,
             "output_bytes": ma.output_size_in_bytes,
             "temp_bytes": ma.temp_size_in_bytes,
+            "alias_bytes": ma.alias_size_in_bytes,
             "peak_bytes": (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                            + ma.output_size_in_bytes - ma.alias_size_in_bytes),
         },
@@ -320,8 +323,10 @@ def main():
         json.dump(rec, f, indent=2)
     status = rec["status"]
     mem = rec.get("memory", {}).get("peak_bytes", 0) / 2**30
+    alias = rec.get("memory", {}).get("alias_bytes", 0) / 2**30
     print(f"[dryrun] {rec['arch']} x {rec['shape']} x {rec['mesh']}: {status}"
-          + (f"  peak={mem:.2f} GiB/dev  dot_flops/dev={rec['cost']['dot_flops_per_device']:.3g}"
+          + (f"  peak={mem:.2f} GiB/dev  alias={alias:.2f} GiB/dev  "
+             f"dot_flops/dev={rec['cost']['dot_flops_per_device']:.3g}"
              if status == "ok" else "")
           + (f"  ({rec.get('note') or rec.get('error', '')})"
              if rec.get("note") or rec.get("error") else ""))

@@ -354,21 +354,24 @@ def attn_layer_step(p, x, kcache, vcache, slot_pos, lengths, cfg: ModelConfig,
     return x, new_k, new_v, new_ks, new_vs, aux
 
 
-def attn_layer_step_paged(p, x, kcache, vcache, tbl, lengths, cfg: ModelConfig,
-                          seg: Segment, aux, use_rope=True, kscale=None,
-                          vscale=None):
-    """Block-decode attention against the POOLED paged cache.
+def attn_layer_step_paged(p, x, pool, layer, tbl, lengths, cfg: ModelConfig,
+                          seg: Segment, aux, use_rope=True):
+    """Block-decode attention of layer `layer` against the POOLED paged cache.
 
-    kcache/vcache: (P, page_size, KV, hd) physical pages shared by every
-    lane (page 0 = null page, never allocated).  tbl: (B, MPS) int32 block
-    table — logical position t of lane b lives at physical slot
+    pool: the segment's whole pool, (kp, vp) or with int8 scales (kp, vp,
+    ksp, vsp), each (n, P, page_size, ...): physical pages shared by every
+    lane (page 0 = null page, never allocated), indexed by the traced
+    `layer`.  The block is scattered into, and the lane views gathered out
+    of, the whole pool, so a pool carried through the layer scan is
+    updated in place and never copied.  tbl: (B, MPS) int32 block table —
+    logical position t of lane b lives at physical slot
     ``tbl[b, t // ps] * ps + t % ps``; -1 entries clamp onto the null page
     so eager writes from dead lanes are harmless.  Speculative rollback is
     identical to the contiguous path: lengths simply don't advance past the
     accepted prefix and the stale slots are overwritten next block.
-    Returns (x, new_k, new_v, new_ks, new_vs, aux)."""
+    Returns (x, new_pool, aux)."""
     B, T = x.shape[:2]
-    Pp, ps = kcache.shape[:2]
+    n, Pp, ps = pool[0].shape[:3]
     MPS = tbl.shape[1]
     Lv = MPS * ps                                 # per-lane logical capacity
     from repro.launch.hints import hint
@@ -384,7 +387,7 @@ def attn_layer_step_paged(p, x, kcache, vcache, tbl, lengths, cfg: ModelConfig,
         k = apply_rope(k, qpos, cfg.rope_theta)
 
     def flat(c):
-        return c.reshape((Pp * ps,) + c.shape[2:])
+        return c.reshape((n, Pp * ps) + c.shape[3:])
 
     # eager paged write: scatter the T-token block through the block table.
     # Distinct lanes own disjoint pages, so indices never collide except on
@@ -394,18 +397,16 @@ def attn_layer_step_paged(p, x, kcache, vcache, tbl, lengths, cfg: ModelConfig,
     wphys = wphys.reshape(-1)
 
     def write(cache, blk):
-        return flat(cache).at[wphys].set(
+        return flat(cache).at[layer, wphys].set(
             blk.reshape((B * T,) + blk.shape[2:]).astype(cache.dtype)
         ).reshape(cache.shape)
 
-    new_ks = new_vs = None
     if cfg.kv_quant:
-        kq, ks_blk = kv_quantize(k)
-        vq, vs_blk = kv_quantize(v)
-        new_k, new_v = write(kcache, kq), write(vcache, vq)
-        new_ks, new_vs = write(kscale, ks_blk), write(vscale, vs_blk)
+        (kq, ks_blk), (vq, vs_blk) = kv_quantize(k), kv_quantize(v)
+        blocks = (kq, vq, ks_blk, vs_blk)
     else:
-        new_k, new_v = write(kcache, k), write(vcache, v)
+        blocks = (k, v)
+    new_pool = tuple(write(c, b) for c, b in zip(pool, blocks))
 
     # gather this lane's logical view back out of the pool (the Pallas
     # paged_decode_attention kernel fetches the same tiles page-by-page via
@@ -413,18 +414,18 @@ def attn_layer_step_paged(p, x, kcache, vcache, tbl, lengths, cfg: ModelConfig,
     j = jnp.arange(Lv)
     rpage, rphys = logical_to_physical(
         tbl, jnp.broadcast_to(j[None, :], (B, Lv)), ps)           # (B, Lv)
-    k_eff = flat(new_k)[rphys]                                    # (B,Lv,KV,hd)
-    v_eff = flat(new_v)[rphys]
+    eff = [flat(c)[layer, rphys] for c in new_pool]               # (B,Lv,...)
+    k_eff, v_eff = eff[0], eff[1]
     if cfg.kv_quant:
-        k_eff = kv_dequantize(k_eff, flat(new_ks)[rphys], x.dtype)
-        v_eff = kv_dequantize(v_eff, flat(new_vs)[rphys], x.dtype)
+        k_eff = kv_dequantize(k_eff, eff[2], x.dtype)
+        v_eff = kv_dequantize(v_eff, eff[3], x.dtype)
     slot_pos = jnp.where((rpage >= 0) & (j[None, :] < lengths[:, None] + T),
                          j[None, :], -1)
     mask = (slot_pos[:, None, :] <= qpos[:, :, None]) & (slot_pos[:, None, :] >= 0)
     out = attend(q, k_eff, v_eff, mask)
     x = x + out.reshape(B, T, -1) @ p["wo"]
     x, aux = _ffn(p, x, cfg, seg.ffn, aux, dropless=True)
-    return x, new_k, new_v, new_ks, new_vs, aux
+    return x, new_pool, aux
 
 
 def mla_layer_step(p, x, ckv_cache, krope_cache, lengths, cfg, seg, aux):
@@ -493,28 +494,21 @@ def run_segment_step(sp, x, seg_cache, cross_cache, lengths, cfg: ModelConfig,
     use_rope = cfg.arch_type != "audio"
 
     if "kp" in seg_cache:                  # pooled paged full attention
-        quant = cfg.kv_quant
+        # the pool rides the layer scan's CARRY, each layer writing and
+        # reading its own index in place; scanned as xs/ys it would come
+        # out as a fresh stacked buffer, a whole-pool copy per block
+        keys = ("kp", "vp") + (("ksp", "vsp") if cfg.kv_quant else ())
 
-        def body(carry, xs):
-            x, aux = carry
-            ks = vs = None
-            lp, kc, vc = xs[:3]
-            if quant:
-                ks, vs = xs[3], xs[4]
-            x, nk, nv, nks, nvs, aux = attn_layer_step_paged(
-                lp, x, kc, vc, tbl, lengths, cfg, seg, aux, use_rope,
-                kscale=ks, vscale=vs)
-            ys = (nk, nv) + ((nks, nvs) if quant else ())
-            return (x, aux), ys
+        def body(carry, lp):
+            x, aux, layer, pool = carry
+            x, pool, aux = attn_layer_step_paged(
+                lp, x, pool, layer, tbl, lengths, cfg, seg, aux, use_rope)
+            return (x, aux, layer + 1, pool), None
 
-        xs = (sp, seg_cache["kp"], seg_cache["vp"])
-        if quant:
-            xs = xs + (seg_cache["ksp"], seg_cache["vsp"])
-        (x, aux), ys = jax.lax.scan(body, (x, aux0), xs)
-        new_c = {"kp": ys[0], "vp": ys[1]}
-        if quant:
-            new_c["ksp"], new_c["vsp"] = ys[2], ys[3]
-        return x, new_c, {}, aux
+        (x, aux, _, pool), _ = jax.lax.scan(
+            body, (x, aux0, jnp.int32(0), tuple(seg_cache[k] for k in keys)),
+            sp)
+        return x, dict(zip(keys, pool)), {}, aux
 
     if seg.kind == "ssm":
         def body(carry, xs):
@@ -693,8 +687,11 @@ def set_block_tables(cache: dict, tbl: jax.Array) -> dict:
     """Replace the WHOLE block table (B, MPS) in one device op.  The serving
     engine keeps a host-side mirror of the table and batches every per-lane
     page-growth row update of a tick into this single push, instead of one
-    ``map_slot_pages`` dispatch per lane per allocation.  No KV moves."""
-    return dict(cache, tbl=tbl.astype(jnp.int32))
+    ``map_slot_pages`` dispatch per lane per allocation.  No KV moves.  The
+    table is written over the cache's own, so a donated cache is updated in
+    place whole."""
+    return dict(cache, tbl=jax.lax.dynamic_update_slice(
+        cache["tbl"], tbl.astype(jnp.int32), (0, 0)))
 
 
 def fill_cache_from_full(cfg: ModelConfig, cache: dict, contribs: dict,

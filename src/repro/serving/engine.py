@@ -322,6 +322,10 @@ class ServingEngine:
         self.sync_every = S
         eos = self.eos_id
 
+        # The supersteps take the cache undonated, so a wrapper around them
+        # (the benchmark harness's fault injection) may still read the cache
+        # it passed in: one pool copy per dispatch, into the output.  The
+        # programs below take it donated and write it in place.
         def superstep(params, dvi_params, pending, cache, buf, done, budget):
             return spec_mod.spec_superstep(
                 model, params, dvi_params, pending, cache, steps=S,
@@ -405,7 +409,7 @@ class ServingEngine:
             pending = jax.lax.dynamic_update_slice_in_dim(
                 pending, prompt[-1:], slot, 0)
             return pending, cache
-        self._admit_fn = jax.jit(admit)
+        self._admit_fn = jax.jit(admit, donate_argnums=(1, 2))
 
         @jax.named_scope(scopes.PREFILL_ADMIT)
         def admit_paged(params, cache, pending, prompt, slot, row):
@@ -418,7 +422,7 @@ class ServingEngine:
             pending = jax.lax.dynamic_update_slice_in_dim(
                 pending, prompt[-1:], slot, 0)
             return pending, cache
-        self._admit_paged_fn = jax.jit(admit_paged)
+        self._admit_paged_fn = jax.jit(admit_paged, donate_argnums=(1, 2))
 
         @jax.named_scope(scopes.PREFILL_ADMIT)
         def admit_prefix(cache, pending, slot, row, length, cow_src, cow_dst,
@@ -437,7 +441,7 @@ class ServingEngine:
             pending = jax.lax.dynamic_update_slice_in_dim(
                 pending, jnp.where(live, tok, cur[0])[None], slot, 0)
             return pending, cache
-        self._admit_prefix_fn = jax.jit(admit_prefix)
+        self._admit_prefix_fn = jax.jit(admit_prefix, donate_argnums=(0, 1))
 
         @jax.named_scope(scopes.PREFILL_ADMIT)
         def admit_chunk(params, cache, chunk, slot):
@@ -448,7 +452,7 @@ class ServingEngine:
             _, pc, _ = model.prefill(params, chunk[None, :],
                                      max_len=chunk.shape[0])
             return tfm.insert_slot(cfg, cache, pc, slot)
-        self._admit_chunk_fn = jax.jit(admit_chunk)
+        self._admit_chunk_fn = jax.jit(admit_chunk, donate_argnums=(1,))
 
         @jax.named_scope(scopes.PREFILL_CHUNK)
         def chunk_step(params, cache, pending, tokens, take, finish_tok,
@@ -459,11 +463,12 @@ class ServingEngine:
             # so they can enter THIS tick's superstep
             _, cache = model.prefill_chunk(params, tokens, cache, take)
             return jnp.where(finished, finish_tok, pending), cache
-        self._chunk_fn = jax.jit(chunk_step)
+        self._chunk_fn = jax.jit(chunk_step, donate_argnums=(1, 2))
 
-        self._set_tbl_fn = jax.jit(tfm.set_block_tables)
+        self._set_tbl_fn = jax.jit(tfm.set_block_tables, donate_argnums=(0,))
         self._reset_fn = jax.jit(
-            lambda cache, slot: tfm.reset_slot(cfg, cache, slot))
+            lambda cache, slot: tfm.reset_slot(cfg, cache, slot),
+            donate_argnums=(0,))
 
     # ------------------------------------------------------------------
     # submission
@@ -650,7 +655,8 @@ class ServingEngine:
         if self.paged:
             self._pool.free(uid)         # decref: shared pages survive in
             self._tbl_host[s] = -1       # the prefix cache, owned ones free
-        self._cache = self._reset_fn(self._cache, jnp.int32(s))
+        self._cache = self._donated(self._reset_fn, self._cache,
+                                    jnp.int32(s))
         self._slots[s] = None
         self._done[s] = True
         self._preempted.pop(uid, None)
@@ -971,9 +977,9 @@ class ServingEngine:
                 row = np.full(self._mps, -1, np.int32)
                 row[:len(owned)] = owned
                 self._tbl_host[slot] = row
-                self._pending, self._cache = self._admit_prefix_fn(
-                    self._cache, self._pending, jnp.int32(slot),
-                    jnp.asarray(row), jnp.int32(warm),
+                self._pending, self._cache = self._donated(
+                    self._admit_prefix_fn, self._cache, self._pending,
+                    jnp.int32(slot), jnp.asarray(row), jnp.int32(warm),
                     jnp.int32(hit.cow_page), jnp.int32(cow_dst),
                     jnp.asarray(prompt[-1]), jnp.asarray(tail == 0))
                 c1, chunked = warm, tail > 0
@@ -1003,10 +1009,10 @@ class ServingEngine:
                 self._tbl_host[slot] = row
                 # chunked: prefill just prompt[:c1]; the pending it sets is
                 # a placeholder, rewritten in-graph by the finishing chunk
-                self._pending, self._cache = self._admit_paged_fn(
-                    self.params, self._cache, self._pending,
-                    jnp.asarray(prompt[:c1 + 1]), jnp.int32(slot),
-                    jnp.asarray(row))
+                self._pending, self._cache = self._donated(
+                    self._admit_paged_fn, self.params, self._cache,
+                    self._pending, jnp.asarray(prompt[:c1 + 1]),
+                    jnp.int32(slot), jnp.asarray(row))
                 # one-shot cold admission caches the whole prompt prefix in
                 # one go — publish it for the next tenant immediately
                 if self.prefix_cache and not chunked:
@@ -1014,13 +1020,13 @@ class ServingEngine:
             else:
                 self._tq.take(req)
                 if chunked:
-                    self._cache = self._admit_chunk_fn(
-                        self.params, self._cache, jnp.asarray(prompt[:c1]),
-                        jnp.int32(slot))
+                    self._cache = self._donated(
+                        self._admit_chunk_fn, self.params, self._cache,
+                        jnp.asarray(prompt[:c1]), jnp.int32(slot))
                 else:
-                    self._pending, self._cache = self._admit_fn(
-                        self.params, self._cache, self._pending,
-                        jnp.asarray(prompt), jnp.int32(slot))
+                    self._pending, self._cache = self._donated(
+                        self._admit_fn, self.params, self._cache,
+                        self._pending, jnp.asarray(prompt), jnp.int32(slot))
             orig_prompt, gen0, blocks0, wall0, seq0 = self._preempted.pop(
                 req.uid, (prompt, [], 0, 0.0, None))
             if seq0 is None:             # fresh request; replays keep their
@@ -1093,7 +1099,8 @@ class ServingEngine:
             uid=st.uid, prompt=combined, max_new=st.max_new,
             tenant=st.handle.tenant if st.handle is not None else "default",
             priority=st.handle.priority if st.handle is not None else 0))
-        self._cache = self._reset_fn(self._cache, jnp.int32(slot))
+        self._cache = self._donated(self._reset_fn, self._cache,
+                                    jnp.int32(slot))
         tr = self.telem.tracer
         if tr is not None:
             now = self.clock()
@@ -1150,8 +1157,8 @@ class ServingEngine:
                     dirty = True
                 break
         if dirty:
-            self._cache = self._set_tbl_fn(self._cache,
-                                           jnp.array(self._tbl_host))
+            self._cache = self._donated(self._set_tbl_fn, self._cache,
+                                        jnp.array(self._tbl_host))
 
     def _sync_row(self, s: int, uid: int) -> None:
         """Mirror lane `s`'s pool ownership into the host block table
@@ -1229,14 +1236,15 @@ class ServingEngine:
                 finished[s] = True
                 finish_tok[s] = st.pf_prompt[-1]
         if dirty:
-            self._cache = self._set_tbl_fn(self._cache,
-                                           jnp.array(self._tbl_host))
+            self._cache = self._donated(self._set_tbl_fn, self._cache,
+                                        jnp.array(self._tbl_host))
         if not take.any() and not finished.any():
             return
         t_c0 = self.clock()
-        self._pending, self._cache = self._chunk_fn(
-            self.params, self._cache, self._pending, jnp.asarray(tokens),
-            jnp.asarray(take), jnp.asarray(finish_tok), jnp.asarray(finished))
+        self._pending, self._cache = self._donated(
+            self._chunk_fn, self.params, self._cache, self._pending,
+            jnp.asarray(tokens), jnp.asarray(take), jnp.asarray(finish_tok),
+            jnp.asarray(finished))
         t_c1 = self.clock()
         tick_tokens = int(take.sum())
         self.stats["prefill_chunks"] += 1
@@ -1487,7 +1495,8 @@ class ServingEngine:
                 if self.paged:
                     self._pool.free(st.uid)   # copy-free eviction: pages
                     self._tbl_host[s] = -1    # recycle host-side
-                self._cache = self._reset_fn(self._cache, jnp.int32(s))
+                self._cache = self._donated(self._reset_fn, self._cache,
+                                            jnp.int32(s))
                 self._slots[s] = None
                 self._done[s] = True
 
@@ -1523,6 +1532,14 @@ class ServingEngine:
         if fold_note is not None:
             self._train_staged.append(fold_note)
         return outs
+
+    def _donated(self, fn, *a):
+        """``fn(*a)`` for a serving program that takes the cache (and the
+        pending tokens, where it returns them) donated: it writes them in
+        place, so the handles passed in are dead once it returns and only
+        its outputs may be read.  Counted in ``kv_pool_donations``."""
+        self.stats["kv_pool_donations"] += 1
+        return fn(*a)
 
     def _phase(self, name: str, fn, *a, label: Optional[str] = None):
         """``fn(*a)``; with the tracer on, inside a phase span ``name`` on

@@ -44,6 +44,10 @@ dvi_serving_preemptions_total                  counter    paged-pool preempt-or-
 dvi_serving_host_syncs_total                   counter    device->host syncs on the hot path
 dvi_serving_sync_wait_seconds_total            counter    host time blocked on the device
 dvi_serving_dispatches_total                   counter    superstep dispatches
+dvi_serving_kv_pool_donations_total            counter    dispatches that took the KV cache
+                                                          donated, written in place
+                                                          (admission, chunk step, block
+                                                          tables, lane reset)
 dvi_serving_prefill_chunks_total               counter    batched prefill chunk steps
 dvi_serving_prefill_tokens_total               counter    prompt tokens prefilled via chunks
 dvi_serving_kv_watermark_hits_total            counter    admissions blocked on pool headroom
@@ -685,6 +689,8 @@ LEGACY_STATS = {
                     "host seconds blocked on device results"),
     "dispatches": ("dvi_serving_dispatches_total", "counter",
                    "superstep dispatches"),
+    "kv_pool_donations": ("dvi_serving_kv_pool_donations_total", "counter",
+                          "dispatches that took the KV cache donated"),
     "prefill_chunks": ("dvi_serving_prefill_chunks_total", "counter",
                        "batched prefill chunk steps"),
     "prefill_tokens": ("dvi_serving_prefill_tokens_total", "counter",
