@@ -13,8 +13,9 @@ state is freed and the float32 reference reads, for each seed:
   below the reference's best, and the mean gap over every served token
   (what a run compares with ``gap_limit`` and ``mean_gap_limit``);
 * control (``--control-seeds``): the same reading for the token that the
-  reference computed with fp8 linear layers (``model_ref``, mode ``fp8``)
-  puts first, at every position of the same prompts and served tokens.
+  reference computed a precision step below the configuration's (the
+  configuration's model module; ``model_ref``: fp8 linear layers) puts
+  first, at every position of the same prompts and served tokens.
 
 A limit lies above every program reading and below every control reading
 of its number, with more room above the first.  Benchmark runs never call this
@@ -27,7 +28,6 @@ import gc
 import sys
 import time
 
-import model_ref
 import run as bench
 
 
@@ -69,17 +69,16 @@ def readings(cell, seeds, control_seeds, seconds: float) -> tuple:
     del setup
     gc.collect()
 
-    w32 = bench.reference_weights(conf, layout, fp)
-    dm = model_ref.Dims.from_config(conf)
+    w32 = bench.reference_weights(conf, cell.model, layout, fp)
     pad = conf["engine"]["cache_len"]
     prog, cont = [], []
     for seed in seeds:
-        g = model_ref.gaps(dm, w32, served[seed], pad)
+        g = cell.model.gaps(conf, w32, served[seed], pad)
         prog.append(g)
         line = (f"[control] seed {seed}: program widest {g['widest_gap']!r} "
                 f"mean {g['mean_gap']!r} over {g['judged']} tokens")
         if seed in control_seeds:
-            c = model_ref.gaps(dm, w32, served[seed], pad, control=True)
+            c = cell.model.gaps(conf, w32, served[seed], pad, control=True)
             cont.append(c)
             line += (f"; fp8 control widest {c['widest_gap']!r} mean "
                      f"{c['mean_gap']!r}")

@@ -25,6 +25,48 @@ Weights are laid out as a list of layer stacks, each a dict of arrays with a
 leading layer axis; the stacks together are the layers in order.  How the
 layers are cut into stacks is a layout choice only (the harness cuts them
 where the program's own parameter tree is cut, so no copy is needed).
+
+The model module's contract
+---------------------------
+
+A configuration file names its model module under ``"reference"`` (a path
+from the checkout's root); the harness loads it by that path, as it loads a
+metric reader, and knows nothing else of the model.  This file is the
+module of the dense, tied-embedding Qwen3 configurations; a configuration of
+another architecture brings a module of its own with the same functions
+(``run.MODEL_API``), and may import this one for what it shares.  ``conf``
+is the configuration file as a dict; ``w`` the benchmark's weights as
+``init_weights`` returns them, on the device.
+
+* ``program_config(conf)``: the program's ``ModelConfig``, set from the
+  configuration's published keys and its ``dvi`` group, validated.  It
+  states every architecture flag itself, never leaving one to the
+  registry's default.
+* ``program_layout(cfg)``: how the program stacks its layers,
+  ``[(segment name, lo, hi)]`` in order, covering every layer once; it
+  raises for a segment kind the module cannot map.
+* ``init_weights(conf, bounds, table)``: the weights, one stack per
+  ``(lo, hi)`` of ``bounds``, made on the device in one jitted call from
+  the configuration's ``weights`` group alone, with the Markov text's
+  transition ``table`` planted so that the verifier serves it.  The same
+  inputs give bit-identical weights in any process.
+* ``to_program(w, layout)``: the program's parameter tree over the same
+  device arrays (no copy of a large leaf).
+* ``fingerprint(w)``: a float that equal weights give equal, in any
+  process; the harness checks the rebuilt reference weights with it.
+* ``to_float32(w)``: the weights the reference reads.
+* ``gaps(conf, w32, samples, pad_to, control=False)``: over every served
+  token of ``samples`` (pairs of prompt and served token lists), how far
+  the reference's logit of the served token lies below its best:
+  ``{"widest_gap", "mean_gap", "judged"}``.  With ``control`` it judges, in
+  place of the served token, the one that the reference computed a
+  precision step below the configuration's puts first.  It imports nothing
+  of the program and runs after the program's state is freed.
+* ``span_flops(conf, lo, hi)``: model FLOPs of the tokens at positions
+  ``lo .. hi - 1``, each once through the whole model (the ``mfu``
+  reader's count; the work of active parameters only).
+* ``SCOPES`` (optional): device scope names (``jax.named_scope``) that the
+  architecture's program adds to ``scope_reduce.SCOPES``; none here.
 """
 from __future__ import annotations
 
@@ -37,8 +79,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import flops
+
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
+SCOPES: Tuple[str, ...] = ()
 
 
 @dataclass(frozen=True)
@@ -56,8 +101,6 @@ class Dims:
 
     @classmethod
     def from_config(cls, c: dict) -> "Dims":
-        if not c.get("tie_word_embeddings", False):
-            raise ValueError("the reference covers tied embeddings only")
         return cls(layers=c["num_hidden_layers"], d=c["hidden_size"],
                    heads=c["num_attention_heads"],
                    kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
@@ -201,15 +244,28 @@ def _init(dm: Dims, rc: Recipe, bounds: Tuple[Tuple[int, int], ...], key,
             "stacks": stacks}
 
 
-def init_weights(dm: Dims, rc: Recipe, bounds: Sequence[Tuple[int, int]],
-                 split: int, table) -> dict:
-    """The weights, made on the device in one jitted call from the recipe's
-    seed and the Markov text's transition ``table``."""
+def check_bounds(bounds: Sequence[Tuple[int, int]], layers: int) -> tuple:
+    """``bounds`` as a tuple of int pairs, once they cover ``layers`` layers
+    in order."""
     bounds = tuple((int(lo), int(hi)) for lo, hi in bounds)
-    if bounds[0][0] != 0 or bounds[-1][1] != dm.layers or any(
+    if bounds[0][0] != 0 or bounds[-1][1] != layers or any(
             a[1] != b[0] for a, b in zip(bounds, bounds[1:])):
-        raise ValueError(f"stacks {bounds} do not cover {dm.layers} layers")
-    slots = plant_slots(dm, rc, split, table)
+        raise ValueError(f"stacks {bounds} do not cover {layers} layers")
+    return bounds
+
+
+def init_weights(conf: dict, bounds: Sequence[Tuple[int, int]],
+                 table) -> dict:
+    """The weights, made on the device in one jitted call from the recipe's
+    seed and the Markov text's transition ``table``; the drafter's layers
+    are those below the configuration's ``dvi.split_layer``."""
+    if not conf.get("tie_word_embeddings", False):
+        raise ValueError(f"{__file__} covers tied embeddings only; an untied "
+                         f"configuration names a module of its own under "
+                         f"'reference'")
+    dm, rc = Dims.from_config(conf), Recipe.from_config(conf)
+    bounds = check_bounds(bounds, dm.layers)
+    slots = plant_slots(dm, rc, conf["dvi"]["split_layer"], table)
     return _init(dm, rc, bounds, jax.random.PRNGKey(rc.seed),
                  *map(jnp.asarray, slots))
 
@@ -309,36 +365,43 @@ def to_float32(w: dict) -> dict:
     return jax.tree.map(lambda a: a.astype(jnp.float32), w)
 
 
-@partial(jax.jit, static_argnums=(0, 3))
-def served_gaps(dm: Dims, w32: dict, seq, control: bool = False):
+@partial(jax.jit, static_argnums=(0, 1, 4))
+def served_gaps(fwd, dm: Dims, w32: dict, seq, control: bool = False):
     """For one sequence ``seq`` (T,) of prompt then served tokens: at each
     position, how far the reference's logit of the next token lies below
     the reference's best.  With ``control`` the next token is not the
     served one but the one the fp8 control puts first.  Returns (T,);
-    position ``t`` judges token ``t + 1`` (the last entry is meaningless)."""
+    position ``t`` judges token ``t + 1`` (the last entry is meaningless).
+    ``fwd`` is a ``forward`` with this module's signature and modes."""
     with jax.default_matmul_precision("highest"):
-        ref = forward(w32, seq[None], dm, "f32")[0]
+        ref = fwd(w32, seq[None], dm, "f32")[0]
         if control:
-            nxt = jnp.argmax(forward(w32, seq[None], dm, "fp8")[0], axis=-1)
+            nxt = jnp.argmax(fwd(w32, seq[None], dm, "fp8")[0], axis=-1)
         else:
             nxt = jnp.concatenate([seq[1:], seq[-1:]])
         best = jnp.max(ref, axis=-1)
         return best - jnp.take_along_axis(ref, nxt[:, None], axis=-1)[:, 0]
 
 
-def gaps(dm: Dims, w32: dict, samples: List[Tuple[list, list]],
+def gaps(conf: dict, w32: dict, samples: List[Tuple[list, list]],
          pad_to: int, control: bool = False) -> dict:
     """Over every served token of ``samples`` (pairs of prompt and served
-    tokens): the widest gap, the mean gap and how many tokens were judged.
-    Every sequence is padded to ``pad_to`` so one program serves them all;
-    the padding lies after the judged positions and the attention is
-    causal."""
+    tokens): the widest gap, the mean gap and how many tokens were judged."""
+    return gaps_of(forward, Dims.from_config(conf), w32, samples, pad_to,
+                   control)
+
+
+def gaps_of(fwd, dm: Dims, w32: dict, samples: List[Tuple[list, list]],
+            pad_to: int, control: bool = False) -> dict:
+    """``gaps`` through the reference ``fwd``.  Every sequence is padded to
+    ``pad_to`` so one program serves them all; the padding lies after the
+    judged positions and the attention is causal."""
     worst, total, n = 0.0, 0.0, 0
     for prompt, served in samples:
         seq = np.zeros((pad_to,), np.int32)
         full = list(prompt) + list(served)
         seq[:len(full)] = full
-        g = np.asarray(served_gaps(dm, w32, jnp.asarray(seq), control),
+        g = np.asarray(served_gaps(fwd, dm, w32, jnp.asarray(seq), control),
                        np.float64)
         judged = g[len(prompt) - 1:len(full) - 1]
         if len(judged):
@@ -347,3 +410,63 @@ def gaps(dm: Dims, w32: dict, samples: List[Tuple[list, list]],
             n += len(judged)
     return {"widest_gap": worst, "mean_gap": total / n if n else float("nan"),
             "judged": n}
+
+
+# ---------------------------------------------------------------------------
+# the program's view: its configuration, layers and parameter tree
+# ---------------------------------------------------------------------------
+
+PUBLISHED_TO_PROGRAM = {
+    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
+    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    "tie_word_embeddings": "tie_embeddings", "torch_dtype": "dtype",
+}
+
+
+def program_config(conf: dict):
+    """The program's ModelConfig, set from the configuration file."""
+    import dataclasses
+
+    from repro.configs import get_config
+    base = get_config(conf["registry_name"])
+    kw = {PUBLISHED_TO_PROGRAM[k]: conf[k] for k in PUBLISHED_TO_PROGRAM}
+    kw["rope_theta"] = float(kw["rope_theta"])
+    kw["norm_eps"] = float(kw["norm_eps"])
+    cfg = base.replace(**kw, qk_norm=True, qkv_bias=False, act="silu",
+                       glu=True, sliding_window=0,
+                       dvi=dataclasses.replace(base.dvi, **conf["dvi"]))
+    cfg.validate()
+    return cfg
+
+
+def program_layout(cfg):
+    """How the program stacks its layers: [(segment name, lo, hi)]."""
+    from repro.models import transformer as tfm
+    segs = tfm.model_segments(cfg)
+    for s in segs:
+        if s.kind != "attn" or s.ffn != "dense" or s.cross:
+            raise ValueError(f"segment {s} is not a dense attention stack")
+    return [(s.name, s.start, s.start + s.n) for s in segs]
+
+
+def to_program(w: dict, layout) -> dict:
+    """The program's parameter tree over the same device arrays (norm gains
+    become the program's offsets from 1)."""
+    names = {"ln1": "ln1", "ln2": "ln2", "qn": "qn", "kn": "kn", "wq": "wq",
+             "wk": "wk", "wv": "wv", "wo": "wo", "w_gate": "wi",
+             "w_up": "wg", "w_down": "wo_ff"}
+    offset = {"ln1", "ln2", "qn", "kn"}
+    segs = {}
+    for (name, _, _), st in zip(layout, w["stacks"]):
+        segs[name] = {names[k]: (v - 1.0 if k in offset else v)
+                      for k, v in st.items()}
+    return {"embed": w["embed"], "final_norm": w["norm"] - 1.0,
+            "segments": segs}
+
+
+def span_flops(conf: dict, lo: int, hi: int) -> int:
+    """Model FLOPs of the tokens at ``lo .. hi - 1`` (``flops.py``)."""
+    return flops.span_flops(conf, lo, hi)

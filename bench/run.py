@@ -7,25 +7,28 @@
 Run from the root of a checkout, on a machine that holds the chips the cell
 asks for.  Everything is found by name from ``BENCHMARK.json``: the cell
 names a configuration (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``), and every metric is read by its own reader
-(``bench/metrics/<metric>.py``, a function ``read(run) -> float | None``).
+(``bench/traffic/<mix>.json``), the configuration names its model module
+(its ``"reference"``: weights, reference, the program's view of the model
+and its FLOP count; ``model_ref.py`` states the contract), and every metric
+is read by its own reader (``bench/metrics/<metric>.py``, a function
+``read(run) -> float | None``).
 
 A run:
 
 1. refuses to go on without the chips (exit 2, no result);
 2. sets up: the weights are made from the configuration's seed on the
-   device, with the benchmark's Markov text planted in them
-   (``model_ref``); the
-   program's model, drafter state and engine are built from the
+   device, with the benchmark's Markov text planted in them (the model
+   module); the program's model, drafter state and engine are built from the
    configuration file; an in-process ``ApiServer`` over an ``EngineDriver``
    serves ``POST /v1/completions`` on a loopback port; a fixed warm-up set
    of requests compiles every shape the window will use;
 3. measures for ``--seconds``: a client process (``client.py``, no JAX)
    sends the seeded requests and times each from when it was due.  With
    ``--trace 1`` the engine's lifecycle tracer is on, the benchmark's host
-   spans are set and the profiler traces a stretch in the middle;
+   spans are set and the profiler traces a stretch in the middle, which
+   ``trace_reduce`` and ``scope_reduce`` read;
 4. checks what the window served against the plain float32 reference
-   (``model_ref``), after the program's state is freed: the widest gap by
+   (the model module), after the program's state is freed: the widest gap by
    which a served token's reference logit lies below the reference's best,
    over a seeded sample of finished requests that holds the longest one;
 5. prints, on earlier lines, the compiles inside the window, peak device
@@ -68,7 +71,7 @@ for p in (str(BENCH), str(ROOT / "src")):
 import numpy as np  # noqa: E402
 
 import markov  # noqa: E402
-import model_ref  # noqa: E402
+import scope_reduce  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
 
@@ -77,6 +80,9 @@ GRACE_S = 60.0
 GAP_LIMITS = {"widest_gap": "gap_limit", "mean_gap": "mean_gap_limit"}
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 LOG = "[bench]"
+# what a configuration's model module provides (``model_ref.py``'s docstring)
+MODEL_API = ("program_config", "program_layout", "init_weights",
+             "to_program", "fingerprint", "to_float32", "gaps", "span_flops")
 
 
 class NoChip(RuntimeError):
@@ -102,6 +108,7 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     bench_dir: Path
+    model: object
 
 
 def applies(metric: dict, cell: str) -> bool:
@@ -114,24 +121,56 @@ def load_cell(bench_dir: Path, workload: str) -> Cell:
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     w = cells[workload]
-    conf = {c["name"]: c for c in spec["configs"]}[w["config"]]
+    conf_file = bench_dir.parent / {c["name"]: c for c in spec["configs"]}[
+        w["config"]]["file"]
+    conf = json.loads(conf_file.read_text())
+    model = load_model(bench_dir.parent, conf_file, conf)
     return Cell(
         name=workload, chips=int(w["chips"]), config_name=w["config"],
-        config=json.loads((bench_dir.parent / conf["file"]).read_text()),
-        mix_name=w["traffic"],
+        config=conf, mix_name=w["traffic"],
         mix=traffic.load_mix(bench_dir / "traffic" / f"{w['traffic']}.json"),
         end_to_end=[m for m in spec["end_to_end"] if applies(m, workload)],
         per_layer=[m for m in spec["per_layer"] if applies(m, workload)],
-        bench_dir=bench_dir)
+        bench_dir=bench_dir, model=model)
+
+
+def load_module(path: Path, name: str):
+    """The module at ``path``, under ``name`` in ``sys.modules`` (where a
+    dataclass looks its module up)."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(bench_dir: Path, metric: str):
-    path = bench_dir / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(bench_dir / "metrics" / f"{metric}.py",
+                       f"bench_metric_{metric}").read
+
+
+_MODELS: dict = {}
+
+
+def load_model(root: Path, conf_file: Path, conf: dict):
+    """The model module that the configuration names under ``reference``,
+    loaded once per path so that its jitted functions keep their compiled
+    programs across runs in one process."""
+    if "reference" not in conf:
+        raise ValueError(f"configuration {conf_file} names no model module "
+                         f"('reference')")
+    path = (root / conf["reference"]).resolve()
+    if path not in _MODELS:
+        if not path.is_file():
+            raise ValueError(f"configuration {conf_file}: its model module "
+                             f"{path} does not exist")
+        _MODELS[path] = load_module(path, f"bench_model_{len(_MODELS)}")
+    missing = [f for f in MODEL_API if not callable(
+        getattr(_MODELS[path], f, None))]
+    if missing:
+        raise ValueError(f"configuration {conf_file}: its model module "
+                         f"{path} lacks {', '.join(missing)}")
+    return _MODELS[path]
 
 
 # ---------------------------------------------------------------------------
@@ -198,42 +237,6 @@ class CompileLog:
 # set-up: weights, program, server
 # ---------------------------------------------------------------------------
 
-PUBLISHED_TO_PROGRAM = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings", "torch_dtype": "dtype",
-}
-
-
-def program_config(conf: dict):
-    """The program's ModelConfig, set from the configuration file."""
-    import dataclasses
-
-    from repro.configs import get_config
-    base = get_config(conf["registry_name"])
-    kw = {PUBLISHED_TO_PROGRAM[k]: conf[k] for k in PUBLISHED_TO_PROGRAM}
-    kw["rope_theta"] = float(kw["rope_theta"])
-    kw["norm_eps"] = float(kw["norm_eps"])
-    cfg = base.replace(**kw, qk_norm=True, qkv_bias=False, act="silu",
-                       glu=True, sliding_window=0,
-                       dvi=dataclasses.replace(base.dvi, **conf["dvi"]))
-    cfg.validate()
-    return cfg
-
-
-def program_layout(cfg):
-    """How the program stacks its layers: [(segment name, lo, hi)]."""
-    from repro.models import transformer as tfm
-    segs = tfm.model_segments(cfg)
-    for s in segs:
-        if s.kind != "attn" or s.ffn != "dense" or s.cross:
-            raise ValueError(f"segment {s} is not a dense attention stack")
-    return [(s.name, s.start, s.start + s.n) for s in segs]
-
-
 def markov_text(conf: dict) -> markov.MarkovText:
     """The Markov text of a configuration: planted in its weights, and what
     its traffic's prompts are drawn from."""
@@ -241,26 +244,11 @@ def markov_text(conf: dict) -> markov.MarkovText:
                              span=conf["weights"]["markov_span"])
 
 
-def bench_weights(conf: dict, bounds) -> dict:
-    """The benchmark's weights, made from the configuration's recipe."""
-    return model_ref.init_weights(
-        model_ref.Dims.from_config(conf), model_ref.Recipe.from_config(conf),
-        bounds, conf["dvi"]["split_layer"], markov_text(conf).table())
-
-
-def to_program(w: dict, layout) -> dict:
-    """The program's parameter tree over the same device arrays (norm gains
-    become the program's offsets from 1)."""
-    names = {"ln1": "ln1", "ln2": "ln2", "qn": "qn", "kn": "kn", "wq": "wq",
-             "wk": "wk", "wv": "wv", "wo": "wo", "w_gate": "wi",
-             "w_up": "wg", "w_down": "wo_ff"}
-    offset = {"ln1", "ln2", "qn", "kn"}
-    segs = {}
-    for (name, _, _), st in zip(layout, w["stacks"]):
-        segs[name] = {names[k]: (v - 1.0 if k in offset else v)
-                      for k, v in st.items()}
-    return {"embed": w["embed"], "final_norm": w["norm"] - 1.0,
-            "segments": segs}
+def bench_weights(conf: dict, model, layout) -> dict:
+    """The benchmark's weights, made by the model module from the
+    configuration's recipe, one stack per segment of ``layout``."""
+    return model.init_weights(conf, [(lo, hi) for _, lo, hi in layout],
+                              markov_text(conf).table())
 
 
 @dataclass
@@ -282,13 +270,13 @@ def build(cell: Cell, trace: bool) -> Setup:
     from repro.serving.config import EngineConfig, build_engine
     from repro.serving.http import ApiServer, EngineDriver
 
-    conf = cell.config
-    cfg = program_config(conf)
-    layout = program_layout(cfg)
-    w = bench_weights(conf, [(lo, hi) for _, lo, hi in layout])
-    fp = model_ref.fingerprint(w)
+    conf, mm = cell.config, cell.model
+    cfg = mm.program_config(conf)
+    layout = mm.program_layout(cfg)
+    w = bench_weights(conf, mm, layout)
+    fp = mm.fingerprint(w)
     log(f"weights: {conf['weights']}, fingerprint {fp!r}")
-    params = to_program(w, layout)
+    params = mm.to_program(w, layout)
     del w
     model = build_model(cfg)
     state = online_mod.init_trainer(
@@ -475,15 +463,16 @@ def stream_faults(records: List[dict], window: List[dict], vocab: int,
     return bad
 
 
-def reference_weights(conf: dict, layout, fingerprint: float) -> dict:
+def reference_weights(conf: dict, model, layout, fingerprint: float
+                      ) -> dict:
     """Rebuild the weights from the seed, check they are the served ones,
     and return them in float32."""
-    w = bench_weights(conf, [(lo, hi) for _, lo, hi in layout])
-    fp = model_ref.fingerprint(w)
+    w = bench_weights(conf, model, layout)
+    fp = model.fingerprint(w)
     if fp != fingerprint:
         raise RuntimeError(f"rebuilt weights differ from the served ones: "
                            f"fingerprint {fp!r} != {fingerprint!r}")
-    w32 = model_ref.to_float32(w)
+    w32 = model.to_float32(w)
     del w
     return w32
 
@@ -523,8 +512,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         device: dict, peaks: dict, compiles: CompileLog, fault=None,
         control: bool = False) -> dict:
     import jax
-    conf = cell.config
+    conf, mm = cell.config, cell.model
     eng = conf["engine"]
+    log(f"model module: {mm.__file__}")
     setup = build(cell, trace)
     if fault is not None:
         fault(setup.engine)
@@ -558,9 +548,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
 
     red = None
     if trace:
-        red = trace_reduce.reduce_file(
-            trace_reduce.find_xplane(tmp),
-            trace_reduce.DEVICE_LINES[device["platform"]])
+        xplane = trace_reduce.find_xplane(tmp)
+        lines = trace_reduce.DEVICE_LINES[device["platform"]]
+        red = trace_reduce.reduce_file(xplane, lines)
+        red.update(scope_reduce.reduce_file(
+            xplane, lines, setup.engine.trace_dict(),
+            scope_reduce.SCOPES + tuple(getattr(mm, "SCOPES", ()))))
         shutil.rmtree(tmp, ignore_errors=True)
 
     records = client["records"]
@@ -578,8 +571,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     data = {"seconds": seconds, "setup_s": setup_s, "loop": plan.loop,
             "records": records, "window": plan.window, "counters": counters,
             "tracer": events, "t0": t0, "trace": red, "config": conf,
-            "peaks": peaks,
-            "grace_s": GRACE_S}
+            "model": mm, "peaks": peaks, "grace_s": GRACE_S}
     metrics = {}
     wanted = cell.per_layer if trace else cell.end_to_end
     for m in wanted:
@@ -606,15 +598,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     read = {"widest_gap": float("nan"), "mean_gap": float("nan"),
             "judged": 0}
     if samples:
-        dm = model_ref.Dims.from_config(conf)
-        w32 = reference_weights(conf, layout, fp)
-        read = model_ref.gaps(dm, w32, samples, eng["cache_len"])
+        w32 = reference_weights(conf, mm, layout, fp)
+        read = mm.gaps(conf, w32, samples, eng["cache_len"])
         log(f"reference: {len(samples)} requests, {read['judged']} served "
             f"tokens judged, widest gap {read['widest_gap']!r}, mean gap "
             f"{read['mean_gap']!r}, {time.monotonic() - t_ref:.1f} s")
         if control:
-            read = model_ref.gaps(dm, w32, samples, eng["cache_len"],
-                                  control=True)
+            read = mm.gaps(conf, w32, samples, eng["cache_len"],
+                           control=True)
             log(f"control: the fp8 reference's first token in place of the "
                 f"served one at every position, widest gap "
                 f"{read['widest_gap']!r}, mean gap {read['mean_gap']!r}")
@@ -654,8 +645,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     out = {"correct": bool(correct), "attempted": attempted,
            "failed": len(failed), "metrics": metrics, "device": dev}
     if red is not None:
-        out["breakdown"] = {"device_ops": red["device_ops"],
-                            "idle_gaps": red["idle_gaps"]}
+        out["breakdown"] = {
+            "device_ops": red["device_ops"], "idle_gaps": red["idle_gaps"],
+            "scopes": scope_reduce.top(red["scopes"]),
+            "program_gaps": scope_reduce.top(red["program_gaps"])}
     out["checks"] = checks
     return out
 
@@ -663,7 +656,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
 def main(argv=None, require_chip: bool = True, bench_dir: Path = BENCH,
          peaks: Optional[dict] = None, fault=None) -> int:
     """One run.  ``require_chip=False`` (tests only) skips the look for a
-    chip and the compile cache, and takes ``peaks`` as given."""
+    chip and the compile cache, and takes ``peaks`` as given.
+
+    A traced run keys the compile cache with the programs' debug metadata:
+    the default key ignores it, so a program cached before its scopes were
+    named would come back with the old ``op_name``s, which the scope split
+    reads.  Untraced runs keep the default key."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -687,8 +685,15 @@ def main(argv=None, require_chip: bool = True, bench_dir: Path = BENCH,
         d = jax.devices()[0]
         device = {"platform": d.platform, "kind": d.device_kind, "count": 1}
     compiles = CompileLog()
-    out = run(cell, args.seed, args.seconds, bool(args.trace), device,
-              peaks, compiles, fault, bool(args.control))
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      keyed or bool(args.trace))
+    try:
+        out = run(cell, args.seed, args.seconds, bool(args.trace), device,
+                  peaks, compiles, fault, bool(args.control))
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          keyed)
     for name, c in out["checks"].items():
         print(f"{LOG} check {name}: {c['value']!r} (limit {c['limit']!r})",
               file=sys.stderr, flush=True)

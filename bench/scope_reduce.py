@@ -17,10 +17,12 @@ first device:
   last among those running takes the instant.  A ``while`` loop's event
   spans its body's operations, so the loop keeps only the time none of them
   runs, and the self times add up to the busy time;
-* scope of an operation: the innermost name of ``SCOPES`` on its ``op_name``
-  path (a fusion carries the path of its root instruction); where there is
+* scope of an operation: the innermost scope name on its ``op_name`` path
+  (a fusion carries the path of its root instruction); where there is
   none, its program (``jit_superstep``); where no program is known,
-  ``other``;
+  ``other``.  The names are ``SCOPES``, those every configuration's program
+  has, plus the ``SCOPES`` of the configuration's model module, which an
+  architecture that names device work of its own adds;
 * program gaps: the stretches with no operation running, each labelled by
   the innermost ``dvi.`` host span that covers its middle (``NO_SPAN`` where
   none does), summed per label.  The profiler records a span only if it
@@ -40,37 +42,39 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import trace_reduce
 
-# the program's scope names (repro.core.scopes.ALL), kept here as data: the
-# benchmark imports nothing of the program
+# the scope names of every configuration's program (repro.core.scopes.ALL,
+# less what a model module adds), kept here as data: the benchmark imports
+# nothing of the program
 SCOPES = ("draft", "verify", "commit", "learn.log", "learn.update",
           "prefill.chunk", "prefill.admit")
 PROGRAM_PREFIX = "dvi."
 OTHER = "other"
 NO_SPAN = "host: no program span"
 EDGE = "host: outside the recorded program spans"
+REST = "rest"
 
 Op = Tuple[float, float, str]                # start_ns, end_ns, label
 
 
-def scope_of(path: str) -> Optional[str]:
-    """The innermost scope on an ``op_name`` path (``tf_op`` adds
+def scope_of(path: str, scopes: Tuple[str, ...] = SCOPES) -> Optional[str]:
+    """The innermost of ``scopes`` on an ``op_name`` path (``tf_op`` adds
     ``:<type>``); a transformation's wrapper counts as what it wraps
     (``transpose(jvp(learn.update))``)."""
     for part in reversed(path.rsplit(":", 1)[0].split("/")):
         name = part.rstrip(")").rsplit("(", 1)[-1]
-        if name in SCOPES:
+        if name in scopes:
             return name
     return None
 
 
 def label(paths: Dict[Tuple[int, str], str], module: Optional[str],
-          name: str) -> str:
+          name: str, scopes: Tuple[str, ...] = SCOPES) -> str:
     """An operation's label: its scope, else its program, else ``OTHER``
     (``paths`` from ``op_paths``; ``module`` as the ``XLA Modules`` line or
     the ``hlo_module`` stat names it)."""
     if module is None:
         return OTHER
-    return scope_of(paths.get((program_id(module), name), "")) or \
+    return scope_of(paths.get((program_id(module), name), ""), scopes) or \
         trace_reduce.module_name(module)
 
 
@@ -185,10 +189,11 @@ def tracer_spans(trace: dict, start_ns: int) -> List[tuple]:
 
 
 def load(path: Path, device_lines: Callable[[str, str], bool]
-         = trace_reduce.is_tpu_ops) -> Tuple[List[Op], List[tuple], int]:
-    """(the first device's operations, each with its label; the stretch
-    span and the program's host spans; the capture's start, wall-clock
-    nanoseconds)."""
+         = trace_reduce.is_tpu_ops, scopes: Tuple[str, ...] = SCOPES
+         ) -> Tuple[List[Op], List[tuple], int]:
+    """(the first device's operations, each labelled by ``scopes``; the
+    stretch span and the program's host spans; the capture's start,
+    wall-clock nanoseconds)."""
     from jax.profiler import ProfileData
     raw = Path(path).read_bytes()
     paths = op_paths(raw, lambda plane: plane.startswith("/device:"))
@@ -213,7 +218,7 @@ def load(path: Path, device_lines: Callable[[str, str], bool]
                     mod = stats.get("hlo_module") or trace_reduce._covering(
                         mods, ev.start_ns)
                     ops.append((ev.start_ns, ev.start_ns + ev.duration_ns,
-                                label(paths, mod, ev.name)))
+                                label(paths, mod, ev.name, scopes)))
             elif plane.name.startswith("/host:"):
                 for ev in line.events:
                     if ev.name.startswith(PROGRAM_PREFIX) or \
@@ -288,16 +293,29 @@ def reduce(ops: List[Op], host: List[tuple], lo: float, hi: float) -> dict:
 
 
 def reduce_file(path: Path, device_lines=trace_reduce.is_tpu_ops,
-                trace: Optional[dict] = None) -> dict:
+                trace: Optional[dict] = None,
+                scopes: Tuple[str, ...] = SCOPES) -> dict:
     """``reduce`` over a ``.xplane.pb``; ``trace``: the program tracer's
-    ``to_dict()``, whose phase spans stand in for lost annotations."""
-    ops, host, start = load(path, device_lines)
+    ``to_dict()``, whose phase spans stand in for lost annotations;
+    ``scopes``: the scope names of the configuration's program."""
+    ops, host, start = load(path, device_lines, scopes)
     st = trace_reduce.stretch_of(host)
     if st is None:
         raise ValueError(f"no {trace_reduce.STRETCH} span in {path}")
     if trace is not None:
         host = host + tracer_spans(trace, start)
     return reduce(ops, host, *st)
+
+
+def top(secs: Dict[str, float], n: int = 10) -> List[list]:
+    """The largest ``n`` entries of ``secs`` as ``[name, seconds]`` pairs,
+    largest first; past ``n``, the last pair is ``REST`` and holds the sum
+    of the smaller ones, so the list keeps the whole's sum (a breakdown
+    list has at most 10 entries)."""
+    items = sorted(secs.items(), key=lambda kv: (-kv[1], kv[0]))
+    if len(items) > n:
+        items = items[:n - 1] + [(REST, sum(v for _, v in items[n - 1:]))]
+    return [[k, v] for k, v in items]
 
 
 def share(run: dict, metric: str, scopes: Tuple[str, ...]) -> Optional[float]:

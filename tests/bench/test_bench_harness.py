@@ -2,13 +2,14 @@
 
 The benchmark itself refuses a CPU (the first test shows it); the others
 call ``run.main`` with the look for a chip skipped, on a temporary copy of
-``bench/`` that adds a tiny configuration and tiny mixes as files of their
-own, as a later change would.  They drive the whole run: set-up, warm-up,
-the client process, the window, the metric readers and the check against
-the float32 reference, and see ``correct`` come out false when the served
-stream is broken underneath (a token altered, tokens sent to the wrong
-lane, a step that returns its KV cache unchanged) and when the fp8 control
-stands in for the program.
+``bench/`` that adds tiny configurations, tiny mixes and a model module of
+another architecture (``untied_model.py``) as files of their own, as a later
+change would.  They drive the whole run: set-up, warm-up, the client
+process, the window, the metric readers and the check against the float32
+reference, and see ``correct`` come out false when the served stream is
+broken underneath (a token altered, tokens sent to the wrong lane, a step
+that returns its KV cache unchanged, the embedding served in the untied
+head's place) and when the fp8 control stands in for the program.
 """
 import io
 import json
@@ -17,6 +18,7 @@ import sys
 from contextlib import redirect_stdout
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -42,8 +44,9 @@ def write_json(path: Path, obj) -> None:
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory) -> Path:
     """A copy of the benchmark whose only additions are files: a tiny
-    configuration, three tiny mixes and one extra metric reader, and their
-    entries in BENCHMARK.json."""
+    configuration, a tiny untied one with its own model module, three tiny
+    mixes and one extra metric reader, and their entries in
+    BENCHMARK.json."""
     root = tmp_path_factory.mktemp("bench_copy")
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -59,6 +62,11 @@ def tiny(tmp_path_factory) -> Path:
                        prefill_chunk=16)
     c["check"] = {"gap_limit": TINY_LIMIT, "sample_requests": 4}
     write_json(root / "bench" / "configs" / "tiny.json", c)
+    shutil.copy(Path(__file__).with_name("untied_model.py"),
+                root / "bench" / "untied_ref.py")
+    write_json(root / "bench" / "configs" / "tiny-untied.json",
+               dict(c, tie_word_embeddings=False,
+                    reference="bench/untied_ref.py"))
     chat = json.loads((BENCH / "traffic" / "chat-poisson.json").read_text())
     chat.update(rate_per_s=4.0, max_total_tokens=250,
                 prompt_tokens={"median": 40, "sigma": 0.5, "min": 8,
@@ -81,17 +89,22 @@ def tiny(tmp_path_factory) -> Path:
         "def read(run):\n"
         "    return sum(len(run['window'][r['i']]['prompt'])\n"
         "               for r in run['records'] if r['sent'] is not None)\n")
-    spec["configs"].append({"name": "tiny", "source": "test",
-                            "file": "bench/configs/tiny.json",
-                            "reduced": [], "why": "CPU test"})
-    cells = ["tiny.chat", "tiny.doc", "tiny.closed"]
-    for cell, mix in zip(cells, ("tiny-chat", "tiny-doc", "tiny-closed")):
-        spec["workloads"].append({"name": cell, "config": "tiny",
+    for name in ("tiny", "tiny-untied"):
+        spec["configs"].append({"name": name, "source": "test",
+                                "file": f"bench/configs/{name}.json",
+                                "reduced": [], "why": "CPU test"})
+    cells = {"tiny.chat": ("tiny", "tiny-chat"),
+             "tiny.doc": ("tiny", "tiny-doc"),
+             "tiny.closed": ("tiny", "tiny-closed"),
+             "tiny-untied.chat": ("tiny-untied", "tiny-chat")}
+    for cell, (conf, mix) in cells.items():
+        spec["workloads"].append({"name": cell, "config": conf,
                                   "traffic": mix, "chips": 1,
                                   "why": "CPU test"})
+    cells = list(cells)
     for m in spec["per_layer"]:
-        m["workloads"] += cells[:2] if m["name"] == "gen_late_p95_ms" \
-            else cells
+        m["workloads"] += [x for x in cells if x != "tiny.closed"] \
+            if m["name"] == "gen_late_p95_ms" else cells
     for m in spec["end_to_end"]:
         if "workloads" in m:
             m["workloads"] += cells
@@ -148,14 +161,17 @@ def test_run_end_to_end(tiny):
 
 
 def test_traced_run_reads_the_layers_and_the_new_metric(tiny):
-    """A new mix and a new metric run by their files alone."""
+    """A new mix and a new metric run by their files alone; every per-layer
+    metric of the cell is read, the scope split's among them."""
     rc, lines = run_cell(tiny, "tiny.chat", seed=7, trace=1)
     res = json.loads(lines[-1])
     assert rc == 0 and res["correct"] is True
     want = {"gen_late_p95_ms", "queue_wait_p95_ms", "prefix_hit_share",
             "tokens_per_block", "sync_wait_share", "device_idle_share",
-            "mfu", "prompt_tokens_sent"}
-    assert set(res["metrics"]) == want
+            "mfu", "prompt_tokens_sent", "draft_share", "verify_share",
+            "commit_share", "learn_share", "prefill_share",
+            "submit_wait_p95_ms", "relay_p95_ms"}
+    assert want <= set(res["metrics"])
     assert res["metrics"]["prompt_tokens_sent"]["value"] > 0
     assert 1 <= res["metrics"]["tokens_per_block"]["value"] <= 5
     dev = res["device"]
@@ -218,12 +234,14 @@ def test_swapped_lanes_are_not_correct(tiny):
 
 def unchanged_cache(engine):
     """A step that returns its state unchanged: every superstep hands back
-    the KV cache it was given, so what it wrote is lost."""
+    a copy of the KV cache it was given, taken before the call (a superstep
+    that takes its cache donated deletes it), so what it wrote is lost."""
     f = engine._superstep_fn
 
     def broken(*a):
+        kept = jax.tree.map(jnp.copy, a[3])
         res = f(*a)
-        return res._replace(cache=a[3])
+        return res._replace(cache=kept)
 
     engine._superstep_fn = broken
 
@@ -252,3 +270,65 @@ def test_fp8_control_fails_where_the_program_passes(tiny):
                                  control_seeds=[1, 2, 3], seconds=3.0)
     widest = [g["widest_gap"] for g in prog]
     assert max(widest) <= TINY_LIMIT < min(c["widest_gap"] for c in ctl)
+
+
+def test_untied_configuration_comes_in_as_files(tiny):
+    """A configuration whose model module is a file of its own (an untied
+    unembedding, served as the program's ``lm_head``) runs end to end."""
+    rc, lines = run_cell(tiny, "tiny-untied.chat", seed=2**31 + 13)
+    assert rc == 0
+    res = json.loads(lines[-1])
+    assert res["correct"] is True and res["failed"] == 0
+    assert set(res["metrics"]) == E2E
+    assert res["checks"]["widest_gap"]["value"] <= TINY_LIMIT
+    path = (tiny / "bench" / "untied_ref.py").resolve()
+    assert sum(f"model module: {path}" in x for x in lines) == 1
+
+
+def embedding_as_head(engine):
+    """The untied model's head read from the wrong matrix: the program
+    serves the embedding in its place."""
+    p = engine.params
+    engine.params = dict(p, lm_head=p["embed"].T)
+
+
+def test_untied_head_fault_is_not_correct(tiny):
+    rc, lines = run_cell(tiny, "tiny-untied.chat", seed=10,
+                         fault=embedding_as_head)
+    res = json.loads(lines[-1])
+    assert rc == 0
+    assert res["correct"] is False
+    assert res["checks"]["widest_gap"]["value"] > TINY_LIMIT
+
+
+PARTIAL_MODULE = (
+    "from model_ref import (fingerprint, gaps, init_weights, program_config,"
+    "\n    program_layout, to_float32, to_program)\n")
+
+
+@pytest.mark.parametrize("fault,names", [
+    ("no key", ("tiny.json", "'reference'")),
+    ("no file", ("tiny.json", "absent_ref.py", "does not exist")),
+    ("no function", ("tiny.json", "partial_ref.py", "lacks span_flops")),
+])
+def test_a_model_module_that_cannot_serve_fails_at_load(tiny, tmp_path,
+                                                         fault, names):
+    """A configuration with no model module, or one that lacks a function
+    of the contract, fails when the cell is loaded, with a message that
+    names the configuration and the module."""
+    root = tmp_path / "copy"
+    shutil.copytree(tiny, root)
+    conf_file = root / "bench" / "configs" / "tiny.json"
+    conf = json.loads(conf_file.read_text())
+    if fault == "no key":
+        del conf["reference"]
+    elif fault == "no file":
+        conf["reference"] = "bench/absent_ref.py"
+    else:
+        (root / "bench" / "partial_ref.py").write_text(PARTIAL_MODULE)
+        conf["reference"] = "bench/partial_ref.py"
+    write_json(conf_file, conf)
+    with pytest.raises(ValueError) as err:
+        bench.load_cell(root / "bench", "tiny.chat")
+    for name in names:
+        assert name in str(err.value)

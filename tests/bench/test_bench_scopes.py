@@ -1,8 +1,9 @@
 """The program's own account of a trace (``bench/scope_reduce.py``): self
 time per device scope and idle gaps labelled by the program's host spans,
 on constructed events and on a trace the profiler records here on the CPU;
-the seven readers built on it and on the program's request spans; and
-``run_scopes.py`` end to end on the tiny copy of the benchmark."""
+the seven readers built on it and on the program's request spans; and the
+split in a traced run of ``run.py`` end to end on the tiny copy of the
+benchmark."""
 import io
 import json
 import sys
@@ -11,11 +12,11 @@ from pathlib import Path
 
 import pytest
 
-BENCH = Path(__file__).resolve().parents[2] / "bench"
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = ROOT / "bench"
 sys.path.insert(0, str(BENCH))
 
 import run as bench  # noqa: E402
-import run_scopes  # noqa: E402
 import scope_reduce as sr  # noqa: E402
 import trace_reduce as tr  # noqa: E402
 from test_bench_harness import PEAKS, tiny  # noqa: E402,F401
@@ -28,8 +29,17 @@ SHARES = {"draft_share": ("draft",), "verify_share": ("verify",),
 
 
 def test_scope_names_are_the_programs():
+    """Every device scope the program names is one the split reads: in the
+    base set, or added by some configuration's model module."""
     from repro.core import scopes
-    assert sr.SCOPES == scopes.ALL
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    added = set()
+    for c in spec["configs"]:
+        conf_file = ROOT / c["file"]
+        model = bench.load_model(ROOT, conf_file,
+                                 json.loads(conf_file.read_text()))
+        added |= set(getattr(model, "SCOPES", ()))
+    assert set(scopes.ALL) <= set(sr.SCOPES) | added
 
 
 @pytest.mark.parametrize("path,want", [
@@ -117,6 +127,42 @@ def test_reduce_constructed():
         "dvi.tick": 0.02, sr.EDGE: 0.01, sr.NO_SPAN: 0.005})
     with pytest.raises(ValueError):
         sr.reduce(ops, host, 5, 5)
+
+
+def test_a_configuration_adds_a_scope():
+    """A scope name that a model module adds takes its operations' time
+    from the base scope around it; without the name they stay there."""
+    inner = "jit(superstep)/while/body/verify/experts/dot_general:"
+    assert sr.scope_of(inner) == "verify"
+    scopes = sr.SCOPES + ("experts",)
+    assert sr.scope_of(inner, scopes) == "experts"
+    paths = {(7, "%fusion.1 = bf16[4] fusion(...)"): inner,
+             (7, "%fusion.2 = bf16[4] fusion(...)"):
+                 "jit(superstep)/while/body/verify/add:"}
+
+    def split(names):
+        ops = [(0, 10 * MS, sr.label(paths, "jit_superstep(7)",
+                                     "%fusion.1 = bf16[4] fusion(...)",
+                                     names)),
+               (10 * MS, 30 * MS, sr.label(paths, "jit_superstep(7)",
+                                           "%fusion.2 = bf16[4] fusion(...)",
+                                           names))]
+        return sr.reduce(ops, [(tr.STRETCH, 0, 40 * MS)], 0, 40 * MS)[
+            "scopes"]
+    assert split(sr.SCOPES) == pytest.approx({"verify": 0.03, "other": 0.0})
+    assert split(scopes) == pytest.approx(
+        {"verify": 0.02, "experts": 0.01, "other": 0.0})
+
+
+def test_top_keeps_the_sum():
+    """A breakdown list holds at most ten pairs, largest first; the smaller
+    entries past the ninth are summed into ``rest``."""
+    secs = {f"s{i}": float(i) for i in range(1, 13)}
+    got = sr.top(secs)
+    assert len(got) == 10 and got[0] == ["s12", 12.0]
+    assert got[-1] == [sr.REST, 1.0 + 2.0 + 3.0]
+    assert sum(v for _, v in got) == sum(secs.values())
+    assert sr.top({"a": 1.0, "b": 2.0}) == [["b", 2.0], ["a", 1.0]]
 
 
 def _message(*fields) -> bytes:
@@ -284,16 +330,13 @@ def test_span_readers(metric, span):
          "t0": 0.0, "seconds": 1.0}) is None
 
 
-def test_run_scopes_on_the_tiny_copy(tiny, monkeypatch):  # noqa: F811
-    """``run_scopes.py`` runs a traced cell as ``run.py`` does, adds the
-    split to the breakdown, reads its metrics there, and leaves ``run``
-    as it found it."""
-    cells = ["tiny.chat"]
-    monkeypatch.setattr(run_scopes, "METRICS", [
-        dict(m, workloads=cells) for m in run_scopes.METRICS])
+def test_run_scopes_on_the_tiny_copy(tiny):  # noqa: F811
+    """A traced run of ``run.py`` adds the scope split to the breakdown,
+    reads the split's metrics and the request spans', and keys the compile
+    cache with the programs' metadata for that run only."""
     out = io.StringIO()
     with redirect_stdout(out):
-        rc = run_scopes.main(
+        rc = bench.main(
             ["--workload", "tiny.chat", "--seed", "21", "--seconds", "3",
              "--trace", "1"], require_chip=False, bench_dir=tiny / "bench",
             peaks=PEAKS)
@@ -306,9 +349,9 @@ def test_run_scopes_on_the_tiny_copy(tiny, monkeypatch):  # noqa: F811
     assert m["relay_p95_ms"]["value"] >= 0
     bd = res["breakdown"]
     assert {"device_ops", "idle_gaps", "scopes", "program_gaps"} <= set(bd)
-    assert sum(bd["scopes"].values()) == pytest.approx(
+    assert sum(v for _, v in bd["scopes"]) == pytest.approx(
         res["device"]["busy_s"], rel=1e-6)
-    assert any(k.startswith("dvi.") for k in bd["program_gaps"])
-    assert bench.run is not None and bench.load_cell.__name__ == "load_cell"
+    assert any(k.startswith("dvi.") for k, _ in bd["program_gaps"])
+    assert all(len(bd[k]) <= 10 for k in bd)
     import jax
     assert not jax.config.jax_compilation_cache_include_metadata_in_key

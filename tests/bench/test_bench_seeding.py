@@ -27,7 +27,8 @@ for name in ("chat-poisson", "docqa-prefix", "chat-closed16"):
     p = traffic.make_plan(mix, text, 2**31 + 3, 5.0, oneshot_max=17)
     blob = json.dumps([p.warmup, p.window], sort_keys=True).encode()
     out[name] = hashlib.sha256(blob).hexdigest()
-w = bench.bench_weights(conf, [(0, 2), (2, 3)])
+w = model_ref.init_weights(conf, [(0, 2), (2, 3)],
+                           bench.markov_text(conf).table())
 out["weights"] = model_ref.fingerprint(w)
 print(json.dumps(out))
 """
